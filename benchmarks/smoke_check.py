@@ -62,8 +62,7 @@ group differing only in the ``gx=<mode>`` segment emitted by
 ``benchmarks.spmm_sweep --gather`` (the up-front baseline keeps its
 unsuffixed name), IF the exposed-gather roofline term (the
 ``exposed_gather_us`` derived field) says some hidden-gather schedule
-(``overlap``'s per-span double-buffer, ``fused``'s in-kernel prefetch)
-strictly shrinks the exposed gather time, the best measured hidden row
+(``overlap``'s per-span double-buffer) strictly shrinks the exposed gather time, the best measured hidden row
 must not run more than ``GATHER_REGRESSION_TOLERANCE`` slower than the
 up-front row — where the model says hiding the gather pays, hiding it
 must never cost real time. Groups where the model prices the schedules
@@ -155,7 +154,7 @@ MESH_REGRESSION_TOLERANCE = 1.10
 # cx=off twin, where the model says the gather pays
 COMPACT_REGRESSION_TOLERANCE = 1.10
 
-# the best hidden-gather (gx=overlap|fused) row may be at most 10% slower
+# the best hidden-gather (gx=overlap) row may be at most 10% slower
 # than its up-front twin, where the exposed-gather model says hiding pays
 GATHER_REGRESSION_TOLERANCE = 1.10
 
@@ -172,29 +171,29 @@ TRANSPOSE_REGRESSION_TOLERANCE = 1.25
 
 _CHUNK_ROW_RE = re.compile(
     r"^(?P<base>.*sellcs\+merge@\d+dev)/chunks=(?P<c>\d+)"
-    r"(?P<cx>/cx=(?:on|off))?(?P<gx>/gx=(?:upfront|overlap|fused))?"
+    r"(?P<cx>/cx=(?:on|off))?(?P<gx>/gx=(?:upfront|overlap))?"
     r"(?P<op>/op=[NT])?/k=(?P<k>\d+)$")
 
 _MESH_ROW_RE = re.compile(
     r"^(?P<base>.*sellcs\+(?:row|merge))@(?P<pd>\d+)x(?P<pm>\d+)mesh"
     r"(?P<chunks>/chunks=\d+)?(?P<cx>/cx=(?:on|off))?"
-    r"(?P<gx>/gx=(?:upfront|overlap|fused))?"
+    r"(?P<gx>/gx=(?:upfront|overlap))?"
     r"(?P<op>/op=[NT])?/k=(?P<k>\d+)$")
 
 _COMPACT_ROW_RE = re.compile(
     r"^(?P<base>.*sellcs\+(?:row|merge)@(?:\d+dev|\d+x\d+mesh)"
     r"(?:/chunks=\d+)?)/cx=(?P<cx>on|off)"
-    r"(?P<gx>/gx=(?:upfront|overlap|fused))?"
+    r"(?P<gx>/gx=(?:upfront|overlap))?"
     r"(?P<op>/op=[NT])?/k=(?P<k>\d+)$")
 
 _TRANSPOSE_ROW_RE = re.compile(
     r"^(?P<base>.*sellcs\+(?:row|merge)@(?:\d+dev|\d+x\d+mesh)"
     r"(?:/chunks=\d+)?(?:/cx=(?:on|off))?"
-    r"(?:/gx=(?:upfront|overlap|fused))?)/op=(?P<op>[NT])/k=(?P<k>\d+)$")
+    r"(?:/gx=(?:upfront|overlap))?)/op=(?P<op>[NT])/k=(?P<k>\d+)$")
 
 _GATHER_ROW_RE = re.compile(
     r"^(?P<base>.*sellcs\+(?:row|merge)@(?:\d+dev|\d+x\d+mesh)"
-    r"(?:/chunks=\d+)?/cx=on)(?P<gx>/gx=(?:upfront|overlap|fused))?"
+    r"(?:/chunks=\d+)?/cx=on)(?P<gx>/gx=(?:upfront|overlap))?"
     r"(?P<op>/op=[NT])?/k=(?P<k>\d+)$")
 
 
@@ -581,7 +580,7 @@ def check_compact_regressions(records: List[dict], origin: str
             continue
         if _backend(rec) in (None, "cpu"):
             continue            # shared X buffer -> nothing to gate
-        # a gx=overlap|fused row pairs with nothing here: the replicated
+        # a gx=overlap row pairs with nothing here: the replicated
         # baseline has no gather to schedule, so only the up-front
         # (unsuffixed) cx=on row gets an off twin — hidden-gather rows
         # land in gx-keyed groups that never complete and are skipped

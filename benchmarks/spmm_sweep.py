@@ -34,8 +34,7 @@ like the mesh gate — a host-platform mesh shares one X buffer).
 ``--gather upfront,overlap`` sweeps the compact-X gather schedule next to
 the up-front one: each compacted (``cx=on``) row grows a ``gx=<mode>``
 sibling per non-default mode (``overlap`` double-buffers the per-span
-gather against the merge chunk stream, ``fused`` folds the indirection
-into the Pallas kernel's scalar prefetch), each priced by the
+gather against the merge chunk stream), each priced by the
 exposed-gather roofline term (``spmm_distributed_gather_s``) and stamped
 with ``exposed_gather_us=`` so ``smoke_check.check_gather_overlap`` can
 gate the hidden-gather rows against their up-front baseline wherever the
@@ -321,7 +320,7 @@ def main(argv=None) -> None:
                          "cx=on row per cx=off row so smoke_check's "
                          "compact gate has its replicated baseline")
     ap.add_argument("--gather", default="upfront",
-                    help="comma-separated subset of upfront,overlap,fused: "
+                    help="comma-separated subset of upfront,overlap: "
                          "sweep the compact-X gather schedule (needs "
                          "--compact-x on) — 'upfront,overlap' emits a "
                          "gx=overlap row per compacted baseline row so "
@@ -350,10 +349,10 @@ def main(argv=None) -> None:
         raise SystemExit(f"--op must be comma-separated N/T entries, "
                          f"got {args.op!r}")
     gathers = tuple(s for s in args.gather.split(",") if s)
-    if not gathers or any(g not in ("upfront", "overlap", "fused")
+    if not gathers or any(g not in ("upfront", "overlap")
                           for g in gathers):
         raise SystemExit(f"--gather must be comma-separated "
-                         f"upfront/overlap/fused entries, got "
+                         f"upfront/overlap entries, got "
                          f"{args.gather!r}")
     if gathers != ("upfront",) and True not in compact_flags:
         raise SystemExit("--gather beyond 'upfront' needs --compact-x on "

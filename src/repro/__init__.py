@@ -61,7 +61,6 @@ Module map
                      ``reshard``) and ``fault_tolerance`` watches step
                      times (``StragglerMonitor``) — both wired into the
                      serve fleet's device-loss path.
-``repro.compat``     shims over jax/Pallas API renames.
 
 Submodules import lazily (nothing heavy happens at ``import repro``).
 """
@@ -69,5 +68,5 @@ __version__ = "0.1.0"
 
 __all__ = [
     "core", "spmm", "kernels", "roofline", "data", "models", "configs",
-    "obs", "launch", "optim", "checkpoint", "runtime", "compat",
+    "obs", "launch", "optim", "checkpoint", "runtime",
 ]

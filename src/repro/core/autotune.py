@@ -57,7 +57,7 @@ class TuneResult:
                                       #   (sellcs on A == A^T only)
     gather: Optional[str] = None      # compact-X gather schedule the
                                       #   distributed score picked
-                                      #   ("upfront"|"overlap"|"fused";
+                                      #   ("upfront"|"overlap";
                                       #   None off the mesh)
     residual: Optional[float] = None  # observed/modeled correction the
                                       #   feedback ledger applied to this
@@ -118,7 +118,7 @@ def autotune(coo: COO, *, num_spmvs: int = 100,
         from repro.spmm import choose_k_tile, spmm
         x = jnp.asarray(rng.standard_normal(
             (coo.shape[1], k)).astype(np.float32))
-        k_tile = choose_k_tile(coo.shape, k, nnz=coo.nnz)
+        k_tile = choose_k_tile(k)
 
         def measure(mat):
             return _measure(lambda: spmm(mat, x, impl="ref"), reps)

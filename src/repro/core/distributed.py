@@ -31,7 +31,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
 from .formats import COO
 from .mergepath import balanced_row_bands
 
@@ -158,7 +157,7 @@ def spmv_row_distributed(sharded: ShardedCOO, x: jax.Array, mesh: Mesh,
         contrib = vals[0][:, None] * x_rep[cols[0]]          # [nnz_pad, k]
         return y_loc.at[0, rows[0]].add(contrib)
 
-    yb = shard_map(
+    yb = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(axis, None), P(axis, None), P(axis, None),
                   P(None, None)),
@@ -196,7 +195,7 @@ def spmv_merge_distributed(sharded: ShardedCOO, x: jax.Array, mesh: Mesh,
                           ).at[offs[0] + rows[0]].add(contrib)
         return jax.lax.psum(y_loc, axis)
 
-    y = shard_map(
+    y = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(axis, None), P(axis, None), P(axis, None), P(axis),
                   P(None, None)),

@@ -305,10 +305,9 @@ CHUNK_CANDIDATES = (1, 2, 4, 8)
 
 # Candidate compact-X gather schedules (repro.spmm.distributed.GATHER_MODES):
 # "upfront" materializes the slab ahead of the mesh region, "overlap" hides
-# per-span slab rebuilds under the chunked merge span loop, "fused" rides
-# col_map on the kernel's scalar prefetch. Executable only with
-# compact_x=True on the SELL-C-σ stream.
-GATHER_CANDIDATES = ("upfront", "overlap", "fused")
+# per-span slab rebuilds under the chunked merge span loop. Executable only
+# with compact_x=True on the SELL-C-σ stream.
+GATHER_CANDIDATES = ("upfront", "overlap")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -334,7 +333,7 @@ class PlanSpec:
     schedule: Optional[str] = None
     algorithm: Optional[str] = None
     structure: Optional[str] = None     # "general" | "symmetric" | unpinned
-    gather: Optional[str] = None        # "upfront"|"overlap"|"fused"|unpinned
+    gather: Optional[str] = None        # "upfront"|"overlap"|unpinned
 
     def canonical(self) -> "PlanSpec":
         """Validate and normalize: mesh factors must agree with
@@ -468,8 +467,7 @@ class DistributedChoice(NamedTuple):
     sparsity-aware X gather beats replication — fifth, ``structure`` —
     ``"symmetric"`` when one-triangle storage wins on a symmetric matrix —
     sixth, and ``gather`` — how the compact-X slab build is scheduled
-    (up-front / overlapped with the span loop / fused into the kernel) —
-    seventh."""
+    (up-front, or overlapped with the span loop) — seventh."""
     algorithm: str
     schedule: str
     num_chunks: int
@@ -543,8 +541,8 @@ def select_distributed(stats: MatrixStats, *, k: int = 1,
     For SELL-C-σ compact candidates the grid also scores the gather
     schedule (:data:`GATHER_CANDIDATES`): the exposed-gather-seconds term
     (:func:`repro.roofline.analysis.spmm_distributed_gather_s`) is fully
-    paid up-front, partially hidden by the chunked span loop, or zero when
-    fused into the kernel prefetch — strict-< keeps ``upfront`` whenever
+    paid up-front or partially hidden by the chunked span loop —
+    strict-< keeps ``upfront`` whenever
     hiding buys nothing (row schedule, one chunk). ``n_touched`` is a
     measured per-shard mean touched-column count from a live plan (e.g.
     the serve path's ``chunk_plan``); without it the model falls back to
@@ -613,7 +611,7 @@ def select_distributed(stats: MatrixStats, *, k: int = 1,
             for compact in compacts:
                 # the gather schedule only exists where there is a gather:
                 # compact SELL-C-σ. "upfront" is scored first so an
-                # overlapped/fused candidate must strictly beat it.
+                # overlapped candidate must strictly beat it.
                 gathers = (GATHER_CANDIDATES
                            if compact and algo == "sellcs"
                            else ("upfront",))

@@ -107,11 +107,14 @@ def spmv_dense_oracle(mat: Matrix, x: Array) -> Array:
 def spmv(mat: Matrix, x: Array, impl: str = "auto") -> Array:
     """Multiply. impl in {"auto", "ref", "pallas", "pallas_interpret"}.
 
-    "auto" uses the Pallas kernel for blocked/CSR formats when running on
-    TPU, otherwise the XLA reference. Kernels live in repro.kernels (imported
-    lazily to keep the core dependency-light)."""
+    "auto" uses the Pallas kernel where one lowers on the running TPU
+    (``repro.spmm.kernels.resolve_impl``: SELL-C-σ), otherwise the XLA
+    reference. Kernels are imported lazily to keep the core
+    dependency-light."""
     from repro.kernels.tiling import TiledSparse
-    from repro.spmm.sellcs import SellCS   # late import: core <- spmm
+    from repro.spmm.kernels import resolve_impl   # late import: core <- spmm
+    from repro.spmm.sellcs import SellCS
+    impl = resolve_impl(impl, mat)
     if impl in ("pallas", "pallas_interpret"):
         interpret = impl == "pallas_interpret"
         from repro.kernels import ops as kops
@@ -125,10 +128,6 @@ def spmv(mat: Matrix, x: Array, impl: str = "auto") -> Array:
         raise TypeError(
             f"no kernel path for {type(mat).__name__}; convert with "
             "repro.kernels.coo_to_tiled for the blocked kernel")
-    if impl == "auto":
-        on_tpu = jax.default_backend() == "tpu"
-        if on_tpu and isinstance(mat, (TiledSparse, CSR, SellCS)):
-            return spmv(mat, x, impl="pallas")
     if isinstance(mat, TiledSparse):
         from repro.kernels.ref import bsr_spmv_ref
         return bsr_spmv_ref(mat, x)

@@ -48,16 +48,17 @@ def rmat(scale: int, edge_factor: int = 16, seed: int = 0,
     nnz = edge_factor * m
     rows = np.zeros(nnz, np.int64)
     cols = np.zeros(nnz, np.int64)
+    right_given_bottom = c / max(1 - a - b, 1e-9)
     for bit in range(scale):
         r = rng.random(nnz)
-        quad_ab = r < a + b           # top half
-        quad_ac_given = rng.random(nnz)
-        go_right_top = (r >= a) & quad_ab
-        go_right_bot = quad_ac_given >= (c / max(1 - a - b, 1e-9))
-        right = np.where(quad_ab, go_right_top, go_right_bot)
-        down = ~quad_ab
-        rows |= down.astype(np.int64) << bit
-        cols |= right.astype(np.int64) << bit
+        q = rng.random(nnz)
+        down = r >= a + b             # bottom half
+        right = np.where(down, q >= right_given_bottom, r >= a)
+        # in place: a shifted int64 temporary per bit is most of the cost
+        np.bitwise_or(rows, np.left_shift(down, bit, dtype=np.int64),
+                      out=rows)
+        np.bitwise_or(cols, np.left_shift(right, bit, dtype=np.int64),
+                      out=cols)
     rows, cols = _dedupe(rows, cols, m, n)
     vals = rng.standard_normal(rows.size).astype(np.float32)
     return rows, cols, vals, (m, n)

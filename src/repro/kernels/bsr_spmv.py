@@ -24,8 +24,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
-from .tiling import TILE_C, TILE_R, TiledSparse
+from .tiling import TILE_C, TILE_R, TiledSparse, interpret_only
 
 DEFAULT_TILES_PER_STEP = 8
 
@@ -61,6 +60,7 @@ def bsr_spmv(ts: TiledSparse, x: jax.Array, *,
              tiles_per_step: int = DEFAULT_TILES_PER_STEP,
              interpret: bool = False) -> jax.Array:
     """y = A @ x for A in TiledSparse form. Returns f32[m]."""
+    interpret_only("bsr_spmv", interpret)
     m, n = ts.shape
     mp, np_ = ts.padded_shape()
     T = ts.num_tiles
@@ -91,7 +91,7 @@ def bsr_spmv(ts: TiledSparse, x: jax.Array, *,
         ],
         out_specs=pl.BlockSpec((mp,), lambda g, *_: (0,)),
     )
-    params = tpu_compiler_params(dimension_semantics=("arbitrary",))
+    params = pltpu.CompilerParams(dimension_semantics=("arbitrary",))
 
     y = pl.pallas_call(
         functools.partial(_kernel, tiles_per_step=TB),
@@ -136,6 +136,7 @@ def bsr_spmm(ts: TiledSparse, x: jax.Array, *,
     feature matrices). Same tile stream as bsr_spmv; the MXU matvec becomes
     a (8,128)@(128,R) matmul — arithmetic intensity grows R-fold, which is
     exactly why SpMM is the preferred form on TPU (DESIGN §2)."""
+    interpret_only("bsr_spmm", interpret)
     m, n = ts.shape
     mp, np_ = ts.padded_shape()
     R = x.shape[1]
@@ -163,7 +164,7 @@ def bsr_spmm(ts: TiledSparse, x: jax.Array, *,
         ],
         out_specs=pl.BlockSpec((mp, R), lambda g, *_: (0, 0)),
     )
-    params = tpu_compiler_params(dimension_semantics=("arbitrary",))
+    params = pltpu.CompilerParams(dimension_semantics=("arbitrary",))
     y = pl.pallas_call(
         functools.partial(_kernel_spmm, tiles_per_step=TB),
         grid_spec=grid_spec,

@@ -14,8 +14,9 @@ paper's sequential carry-out fixup becomes a jnp scatter-add epilogue over
 the (P, R) partials (ops.merge_spmv).
 
 The only irregular memory op left is the x-gather (x[cols]) from a
-VMEM-resident x — a dynamic VMEM gather, the one pattern Mosaic supports for
-this (and trivially correct in interpret mode).
+VMEM-resident x. Mosaic refuses both that in-kernel gather and the (1, D)
+span blocks, so the kernel runs in interpret mode only (``interpret_only``);
+the TPU path is SELL-C-σ.
 """
 from __future__ import annotations
 
@@ -29,6 +30,7 @@ from jax.experimental import pallas as pl
 
 from repro.core.formats import CSR
 from repro.core.mergepath import merge_path_partition_np
+from .tiling import interpret_only
 
 
 def default_num_spans(m: int, nnz: int) -> int:
@@ -107,6 +109,7 @@ def _kernel(cols_ref, vals_ref, seg_ref, x_ref, out_ref, *, r_width: int):
 @functools.partial(jax.jit, static_argnames=("r_width", "interpret"))
 def merge_spmv_partials(plan_cols, plan_vals, plan_seg, x_pad, *,
                         r_width: int, interpret: bool = False):
+    interpret_only("merge_spmv", interpret)
     P, D = plan_cols.shape
     np_ = x_pad.shape[0]
     grid_spec = pl.GridSpec(

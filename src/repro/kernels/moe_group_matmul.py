@@ -20,7 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
 
 M_TILE, N_TILE, K_TILE = 128, 128, 128
 
@@ -69,7 +68,7 @@ def moe_group_matmul_padded(lhs: jax.Array, rhs: jax.Array,
                                lambda i, j, k, te: (i, j)),
         scratch_shapes=[pltpu.VMEM((M_TILE, N_TILE), jnp.float32)],
     )
-    params = tpu_compiler_params(
+    params = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"))
 
     return pl.pallas_call(

@@ -46,6 +46,18 @@ TILE_R = 8      # sublane dimension
 TILE_C = 128    # lane dimension
 
 
+def interpret_only(kernel: str, interpret: bool) -> None:
+    """Refuse a Pallas kernel that does not lower on Mosaic (a
+    VMEM-resident ``[n, ·]`` slab, an in-kernel ``jnp.take`` gather, or a
+    sub-tile block shape): it runs in interpret mode only, and the TPU path
+    raises here instead of silently running something else."""
+    if not interpret:
+        raise NotImplementedError(
+            f"{kernel} does not lower on Mosaic (TPU); run it with "
+            "interpret=True (impl='pallas_interpret'), use impl='ref', or "
+            "convert to SELL-C-σ (coo_to_sellcs), whose kernel does")
+
+
 def _morton_key_np(rows, cols, bits):
     r = np.asarray(rows, np.uint64)
     c = np.asarray(cols, np.uint64)

@@ -11,12 +11,7 @@ from __future__ import annotations
 from typing import Optional, Sequence, Tuple
 
 import jax
-from jax.sharding import Mesh
-
-try:  # jax >= 0.5 explicit-sharding API; absent on the pinned 0.4.x
-    from jax.sharding import AxisType
-except ImportError:
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False,
@@ -37,10 +32,8 @@ def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...],
             "(the dry-run must set "
             "XLA_FLAGS=--xla_force_host_platform_device_count=512 before "
             "any jax import)")
-    kw = {}
-    if AxisType is not None:
-        kw["axis_types"] = (AxisType.Auto,) * len(axes)
-    return jax.make_mesh(shape, axes, devices=devs[:need], **kw)
+    return jax.make_mesh(shape, axes, devices=devs[:need],
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_spmm_mesh(mesh_shape: Tuple[int, int],

@@ -20,10 +20,9 @@ axis column-shards the X/Y k-slabs so per-device psum and replicated-X
 bytes drop by Pm — the k ≫ 128 scaling axis. ``--compact-x on`` partitions
 with per-shard column compaction (each data shard gathers only the X rows
 its nonzeros touch instead of reading the replicated slab; ``auto`` asks
-the traffic model whether the gather pays). ``--gather
-upfront|overlap|fused`` schedules that gather's exposed latency — up-front
-ahead of the mesh region, hidden under the chunked merge span loop, or
-fused into the Pallas kernel's scalar prefetch (``auto`` lets the
+the traffic model whether the gather pays). ``--gather upfront|overlap``
+schedules that gather's exposed latency — up-front ahead of the mesh
+region, or hidden under the chunked merge span loop (``auto`` lets the
 exposed-gather-seconds roofline term pick). On CPU, force host-platform
 devices first:
   XLA_FLAGS=--xla_force_host_platform_device_count=8 PYTHONPATH=src \
@@ -73,9 +72,11 @@ min-of-N protocol (``--reps``), never a single ``perf_counter`` pair.
 from __future__ import annotations
 
 import argparse
+import functools
 import math
 import threading
 import time
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -292,6 +293,20 @@ class _MigrationController:
                 float(self.breakeven))
 
 
+class SpmvServeResult(NamedTuple):
+    """What one ``--mode spmv`` run served: the matrix, the requests in
+    submission order, their batched answers in the same order, the plan
+    installed at the end, and the headline timings."""
+    coo: object                     # COO the operator was built from
+    xs: list
+    ys: list
+    plan: object                    # RealizedPlan
+    t_batched: float
+    t_seq: float
+    flush_p50_s: Optional[float]    # serve/flush_s p50; None without
+                                    #   --metrics
+
+
 def _serving_pass(op, xs, args, reg=None, controller=None):
     """The flush-by-flush serving loop: per-flush wall times into the
     ``serve/flush_s`` histogram (split pre/post-migration when a
@@ -354,6 +369,15 @@ def _print_metrics_summary(reg):
               "autotune(feedback=) will apply to this config's score")
 
 
+@functools.lru_cache(maxsize=1)
+def _suite_coo(matrix: str, scale: float):
+    """The suite matrix as a device COO. The last one is kept, so a
+    process that serves one matrix in turn (``chip_smoke.py``'s row and
+    merge schedules) generates and canonicalizes it once."""
+    from repro.data import matrices
+    return matrices.as_coo(matrices.test_suite(scale=scale)[matrix].make())
+
+
 def serve_spmv(args):
     """Sparse serving demo: batched (one SpMM per flush) vs sequential,
     optionally over a --devices mesh, all through one
@@ -364,19 +388,20 @@ def serve_spmv(args):
     the paper's §5.2 min-of-N discipline; ``--metrics`` additionally
     records phase spans, flush-latency percentiles, migration decision
     inputs and observed-vs-modeled residuals, then dumps them as one
-    ``repro.obs/v1`` JSON document."""
+    ``repro.obs/v1`` JSON document. Returns a :class:`SpmvServeResult`."""
     from repro import obs
-    from repro.core import PlanSpec, matrix_stats, spmv
+    from repro.core import PlanSpec, spmv
     from repro.core.selector import ZERO_CONVERSION_ALGO
     from repro.data import matrices
     from repro.roofline import spmm_arithmetic_intensity
     from repro.spmm import RequestBatcher, SparseOperator
 
-    suite = matrices.test_suite(scale=args.scale)
-    if args.matrix not in suite:
-        raise SystemExit(f"--matrix must be one of {sorted(suite)}")
-    coo = matrices.as_coo(suite[args.matrix].make())
-    stats = matrix_stats(coo)
+    if args.matrix not in matrices.test_suite():
+        raise SystemExit(f"--matrix must be one of "
+                         f"{sorted(matrices.test_suite())}")
+    t0 = time.perf_counter()
+    coo = _suite_coo(args.matrix, args.scale)
+    t_matrix = time.perf_counter() - t0
     # num_spmvs counts k-RHS multiplies: batching turns `requests` SpMVs
     # into ceil(requests / max_batch) SpMM calls
     num_spmms = -(-args.requests // args.max_batch)
@@ -412,7 +437,8 @@ def serve_spmv(args):
             num_devices=args.devices,
             mesh_shape=mesh_shape or (args.devices, 1),
             num_chunks=args.chunks if args.chunks > 0 else None,
-            compact_x=compact, algorithm="sellcs", gather=gather)
+            compact_x=compact, algorithm="sellcs", gather=gather,
+            schedule=None if args.schedule == "auto" else args.schedule)
     else:
         target_spec = PlanSpec(num_devices=1, algorithm="sellcs")
     if args.migrate != "off":
@@ -428,10 +454,16 @@ def serve_spmv(args):
     op = SparseOperator.from_coo(coo, initial_spec, impl=args.impl,
                                  k_hint=args.max_batch,
                                  num_spmvs=num_spmms)
+    t_plan = time.perf_counter() - t0 - t_matrix
+    stats = op.matrix_stats
     algo = op.plan.label
     print(f"[serve-spmv] matrix={args.matrix} m={stats.m} n={stats.n} "
-          f"nnz={stats.nnz} algo={algo} max_batch={args.max_batch}"
+          f"nnz={stats.nnz} algo={algo} impl={op.plan.impl} "
+          f"max_batch={args.max_batch}"
           + (f" migrate={args.migrate}" if args.migrate != "off" else ""))
+    print(f"[serve-spmv] setup: matrix {t_matrix:.3f} s (generate, or "
+          f"reuse the last), plan {t_plan:.3f} s (stats, convert, "
+          f"partition)")
 
     rng = np.random.default_rng(args.seed)
     xs = [jnp.asarray(rng.standard_normal(stats.n).astype(np.float32))
@@ -483,6 +515,7 @@ def serve_spmv(args):
           f"flop/byte at k={args.max_batch}")
     _print_traffic_model(op.spec, op.plan.n_touched, stats, args)
 
+    flush_p50 = None
     if reg is not None or controller is not None:
         # the measured side: per-flush latencies + residual ledger records
         # against the roofline prediction of the plan serving each flush,
@@ -496,11 +529,15 @@ def serve_spmv(args):
             with obs.span("serve/eager_profile"):
                 jax.block_until_ready(op.plan.eager(
                     jnp.stack([x for x in xs[:args.max_batch]], axis=1)))
+        flush = reg.histogram("serve/flush_s")
+        if flush.count:
+            flush_p50 = flush.percentiles()["p50"]
         _print_metrics_summary(reg)
         reg.dump(args.metrics)
         print(f"[serve-spmv] metrics -> {args.metrics}")
         obs.uninstall()
-    return t_batched, t_seq
+    return SpmvServeResult(coo, xs, [out[rid] for rid in rids], op.plan,
+                           t_batched, t_seq, flush_p50)
 
 
 def _print_traffic_model(sp, n_touched, stats, args):
@@ -743,6 +780,10 @@ def main(argv=None):
                          "column-shards the X/Y k-slabs so per-device psum "
                          "and replicated-X bytes drop by Pm (overrides "
                          "--devices with Pd*Pm)")
+    ap.add_argument("--schedule", default="auto",
+                    choices=("auto", "row", "merge"),
+                    help="pin the distributed schedule: row bands or "
+                         "merge spans (auto = core.select_distributed)")
     ap.add_argument("--chunks", type=int, default=0,
                     help="pipeline the merge-schedule psum into this many "
                          "chunks (0 = pick by the roofline overlap model; "
@@ -755,12 +796,11 @@ def main(argv=None):
                          "nonzeros touch (auto = let the traffic model "
                          "decide when the gather beats replication)")
     ap.add_argument("--gather", default="auto",
-                    choices=("auto", "upfront", "overlap", "fused"),
+                    choices=("auto", "upfront", "overlap"),
                     help="compact-X gather schedule: materialize the slab "
-                         "up-front ahead of the mesh region, hide per-span "
-                         "rebuilds under the chunked merge span loop "
-                         "(overlap), or fuse the gather into the Pallas "
-                         "kernel's scalar prefetch (fused); auto = let the "
+                         "up-front ahead of the mesh region, or hide "
+                         "per-span rebuilds under the chunked merge span "
+                         "loop (overlap); auto = let the "
                          "exposed-gather-seconds roofline term pick")
     ap.add_argument("--impl", default="auto",
                     choices=("auto", "ref", "pallas", "pallas_interpret"))
@@ -804,6 +844,11 @@ def main(argv=None):
     ap.add_argument("--gen", type=int, default=16)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    if argv is None:
+        # the command line; a library caller (a test, chip_smoke.py)
+        # chooses its own cache
+        from repro.launch.compile_cache import enable_compile_cache
+        enable_compile_cache()
 
     if args.mode == "spmv":
         return serve_spmv(args)

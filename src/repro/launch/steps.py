@@ -14,7 +14,6 @@ from repro.configs.base import SHAPES, get_config
 from repro.models.model import (ModelConfig, decode_step, init_cache,
                                 init_params, loss_fn, prefill)
 from repro.optim import Optimizer, make_optimizer, warmup_cosine
-from repro.compat import set_mesh
 from .mesh import dp_axes
 from . import shardings as shd
 
@@ -218,7 +217,7 @@ def lower_cell(arch: str, shape_name: str, mesh: Mesh,
     """Lower (no compile) the step function of one cell on ``mesh``."""
     cfg = cfg or cell_config(arch, shape_name, mesh, profile)
     specs = input_specs(arch, shape_name, mesh, cfg)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if specs["kind"] == "train":
             optimizer = default_optimizer(cfg)
             state = abstract_train_state(cfg, optimizer, mesh, profile)

@@ -23,7 +23,6 @@ from repro.data.pipeline import TokenPipeline
 from repro.models.model import init_params
 from repro.optim import make_optimizer, warmup_cosine
 from repro.runtime import Supervisor
-from repro.compat import set_mesh
 from .mesh import make_mesh
 from .steps import TrainState, make_train_step
 from . import shardings as shd
@@ -60,7 +59,7 @@ def main(argv=None):
     pipe = TokenPipeline(vocab=cfg.vocab, batch=args.batch, seq=args.seq,
                          seed=args.seed)
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = init_params(jax.random.PRNGKey(args.seed), cfg)
         params = jax.device_put(params,
                                 shd.param_shardings(params, mesh))

@@ -16,7 +16,6 @@ from typing import NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map
 from .layers import dense_init
 
 Array = jax.Array
@@ -180,7 +179,7 @@ def moe_apply_ep(p, cfg: MoEConfig, x: Array, *, ep_axis: str = "model",
         return y.reshape(Bl, S, d).astype(xl.dtype), aux, dropped
 
     bspec = P(batch_axes, None, None) if batch_axes else P(None, None, None)
-    y, aux, dropped = shard_map(
+    y, aux, dropped = jax.shard_map(
         local,
         in_specs=(bspec, P(None, None), P(ep_axis, None, None),
                   P(ep_axis, None, None), P(ep_axis, None, None)),
@@ -235,7 +234,7 @@ def moe_apply_ep_tp(p, cfg: MoEConfig, x: Array, *, ep_axis: str = "model",
         return y.reshape(Bl, S, d).astype(xl.dtype), aux
 
     bspec = P(batch_axes, None, None) if batch_axes else P(None, None, None)
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         local,
         in_specs=(bspec, P(None, None), P(None, None, ep_axis),
                   P(None, None, ep_axis), P(None, ep_axis, None)),

@@ -44,7 +44,7 @@ def choice_labels(schedule: Optional[str] = None,
     the serve path (which *records*) and autotune (which *queries*) key
     residuals identically: ``schedule``, ``num_chunks``, ``mesh``
     (``"PdxPm"``), ``compact_x`` (``"on"``/``"off"``), ``gather``
-    (``"upfront"``/``"overlap"``/``"fused"``), plus any extras (matrix
+    (``"upfront"``/``"overlap"``), plus any extras (matrix
     name, k, backend)."""
     labels: Dict[str, str] = {}
     if schedule is not None:

@@ -30,11 +30,10 @@ class TimingResult(NamedTuple):
 
 
 def _block(out):
+    """Wait for ``out``'s device work. A failed execution raises here:
+    a timing over work that did not run is no timing."""
     if _jax is not None:
-        try:
-            return _jax.block_until_ready(out)
-        except Exception:
-            return out
+        return _jax.block_until_ready(out)
     return out
 
 

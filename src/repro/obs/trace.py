@@ -149,10 +149,8 @@ def maybe_block(x):
 
     Safe inside ``jit``/``shard_map`` tracing: ``jax.block_until_ready``
     leaves tracers untouched, so instrumented library code needs no
-    eager-vs-traced branch. The disabled path is one global load."""
+    eager-vs-traced branch. The disabled path is one global load. A
+    device execution that failed raises here, inside its span."""
     if _metrics._REGISTRY is None or _jax is None:
         return x
-    try:
-        return _jax.block_until_ready(x)
-    except Exception:
-        return x
+    return _jax.block_until_ready(x)

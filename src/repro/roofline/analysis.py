@@ -149,7 +149,7 @@ def ridge_intensity(peak_flops: float = PEAK_FLOPS_BF16,
 def csr_stream_bytes(nnz: int, m: int, dtype_bytes: int = 4) -> int:
     """Ideal CSR matrix-stream footprint of one multiply: values + column
     indices + row pointer. The single source of truth for the traffic model
-    (shared by choose_k_tile, the selector's k-scaling and the sweep)."""
+    (shared by the selector's k-scaling and the sweep)."""
     return nnz * (4 + dtype_bytes) + 4 * (m + 1)
 
 
@@ -409,18 +409,16 @@ def spmm_distributed_gather_s(m: int, n: int, k: int, num_devices: int,
       :func:`spmm_distributed_collective_s`. Where the executable
       degenerates to up-front (row schedule, ``num_chunks == 1``), so does
       the price.
-    * ``"fused"``: ``col_map`` rides the kernel's scalar prefetch and the
-      stream indexes the full X directly — no slab, nothing exposed.
 
     Zero when the partition is not compact or ``op='T'`` (the transpose
     path has no X gather: X enters slot-permuted). By construction
-    ``fused <= overlap <= upfront`` for any inputs, so a strict-< selector
-    keeps ``upfront`` on ties.
+    ``overlap <= upfront`` for any inputs, so a strict-< selector keeps
+    ``upfront`` on ties.
     """
-    if gather not in ("upfront", "overlap", "fused"):
-        raise ValueError(f"gather must be 'upfront', 'overlap' or 'fused', "
+    if gather not in ("upfront", "overlap"):
+        raise ValueError(f"gather must be 'upfront' or 'overlap', "
                          f"got {gather!r}")
-    if not compact_x or op == "T" or gather == "fused":
+    if not compact_x or op == "T":
         return 0.0
     P = max(int(num_devices), 1)
     Pm = max(int(model_devices), 1)

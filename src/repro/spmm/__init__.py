@@ -29,7 +29,8 @@ from .distributed import (ShardedSellCS, partition_sellcs_nnz,
                           partition_sellcs_rows, rechunk_sellcs,
                           redeal_sellcs, spmm_merge_distributed,
                           spmm_row_distributed)
-from .kernels import choose_k_tile, csr_spmm, sellcs_spmm, tiled_spmm
+from .kernels import (choose_k_tile, csr_spmm, resolve_impl, sellcs_spmm,
+                      tiled_spmm)
 from .operator import (OperatorStats, RealizedPlan, SparseOperator,
                        TransposedOperator, coo_fingerprint, sparse_matmul)
 from .fleet import Fleet, FleetStats
@@ -43,8 +44,8 @@ def spmm(mat, x: jax.Array, *, impl: str = "auto",
     """Multiply ``Y = A @ X`` for any supported format.
 
     impl in {"auto", "ref", "pallas", "pallas_interpret"} — same contract
-    as ``core.spmv.spmv``: "auto" takes the Pallas path on TPU for formats
-    with a kernel, the XLA reference otherwise.
+    as ``core.spmv.spmv``: "auto" takes the Pallas path on TPU where a
+    kernel lowers (``kernels.resolve_impl``), the XLA reference otherwise.
 
     ``op='T'`` computes ``Y = A^T X`` over the same stored stream
     (``X: [m, k]``, ``Y: [n, k]``); the Pallas path supports it on
@@ -55,6 +56,7 @@ def spmm(mat, x: jax.Array, *, impl: str = "auto",
     from repro.kernels.tiling import TiledSparse
     if op not in ("N", "T"):
         raise ValueError(f"op must be 'N' or 'T', got {op!r}")
+    impl = resolve_impl(impl, mat, op)
     if impl in ("pallas", "pallas_interpret"):
         interpret = impl == "pallas_interpret"
         x2 = x[:, None] if x.ndim == 1 else x
@@ -74,11 +76,6 @@ def spmm(mat, x: jax.Array, *, impl: str = "auto",
                 f"no SpMM kernel for {type(mat).__name__}; convert with "
                 "coo_to_sellcs / repro.kernels.coo_to_tiled / coo_to_csr")
         return y[:, 0] if x.ndim == 1 else y
-    if impl == "auto":
-        on_tpu = jax.default_backend() == "tpu"
-        if on_tpu and isinstance(mat, (TiledSparse, CSR, SellCS)) and \
-                (op == "N" or isinstance(mat, SellCS)):
-            return spmm(mat, x, impl="pallas", k_tile=k_tile, op=op)
     return spmm_ref(mat, x, op=op)
 
 

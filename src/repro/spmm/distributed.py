@@ -61,10 +61,7 @@ up-front ``x_pad[col_map]`` slab build is one XLA gather serialized on
 the critical path before the first kernel launch. ``gather="overlap"``
 (chunked merge) rebuilds each span's piece of the slab inside the mesh
 region from the plan's per-span touched split, so span ``i+1``'s gather
-hides under span ``i``'s kernel/psum; ``gather="fused"`` skips the slab
-entirely — ``col_map`` rides the Pallas scalar prefetch next to
-``slice_of`` and the kernel indexes the full X directly. All modes are
-bitwise-identical; ``roofline.spmm_distributed_gather_s`` prices the
+hides under span ``i``'s kernel/psum. Both modes are bitwise-identical; ``roofline.spmm_distributed_gather_s`` prices the
 exposed seconds of each so the selector can choose.
 
 Phase tracing (``repro.obs``): both multiplies carry ``span()`` markers at
@@ -89,7 +86,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.distributed import _check_devices
 from repro.core.mergepath import balanced_row_bands
 from repro.obs import maybe_block, span
@@ -523,7 +519,7 @@ def _prep(sharded: ShardedSellCS, x: jax.Array, mesh: Mesh, axis: str,
     # the global k; kp = kc * pm is the padded global slab width.
     kc = -(-k // pm)
     if use_pallas:
-        kt = k_tile or choose_k_tile(sharded.shape, kc, nnz=sharded.nnz)
+        kt = k_tile or choose_k_tile(kc, chunk=sharded.chunk)
         kc = -(-kc // kt) * kt
     else:
         kt = k_tile
@@ -753,14 +749,14 @@ def _chunk_substreams(sharded: ShardedSellCS, num_chunks: int, *,
 
 
 
-GATHER_MODES = ("upfront", "overlap", "fused")
+GATHER_MODES = ("upfront", "overlap")
 
 
 def _resolve_gather(gather: Optional[str], compact: bool) -> str:
     """Validate the gather-scheduling knob. ``None`` (the default) is the
     up-front gather — byte-identical to the pre-knob behavior. The
-    overlapped and fused modes only exist where a gather exists: a
-    replicated-X stream has nothing to hide."""
+    overlapped mode only exists where a gather exists: a replicated-X
+    stream has nothing to hide."""
     if gather is None:
         return "upfront"
     if gather not in GATHER_MODES:
@@ -775,19 +771,15 @@ def _resolve_gather(gather: Optional[str], compact: bool) -> str:
 
 
 def _local_slots(data, cols, slice_of, x_rep, *, num_slices, chunk,
-                 use_pallas, k_tile, interpret, col_map=None):
-    """Shard-local compute: the PR-1 k-tiled Pallas kernel, or its jnp twin
-    off-TPU. Inputs carry a leading length-1 device-block axis. With
-    ``col_map`` the gather is fused into the kernel: ``x_rep`` is the full
-    (ungathered) X and the kernel indexes it through the map."""
+                 use_pallas, k_tile, interpret):
+    """Shard-local compute: the k-tiled Pallas kernel, or its jnp twin
+    off-TPU. Inputs carry a leading length-1 device-block axis."""
     if use_pallas:
         return sellcs_slots(data[0], cols[0], slice_of[0], x_rep,
                             num_slices=num_slices, chunk=chunk,
-                            k_tile=k_tile, interpret=interpret,
-                            col_map=col_map)
+                            k_tile=k_tile, interpret=interpret)
     return sellcs_slots_ref(data[0], cols[0], slice_of[0], x_rep,
-                            num_slices=num_slices, chunk=chunk,
-                            col_map=col_map)
+                            num_slices=num_slices, chunk=chunk)
 
 
 def _local_slots_t(data, cols, slice_of, x_slots, *, n_out, chunk,
@@ -888,11 +880,9 @@ def spmm_row_distributed(sharded: ShardedSellCS, x: jax.Array, mesh: Mesh,
     the touched-*column* map becomes a touched-*output-row* map.
 
     ``gather=`` schedules the compact-X gather: ``"upfront"`` (default)
-    materializes the slab ahead of the mesh region, ``"fused"`` feeds the
-    full X and lets the kernel index it through ``col_map`` directly (the
-    map rides the Pallas scalar prefetch next to ``slice_of``), and
-    ``"overlap"`` degenerates to up-front here — the row schedule has no
-    span loop to hide the gather under. All modes are bitwise-identical;
+    materializes the slab ahead of the mesh region, and ``"overlap"``
+    degenerates to up-front here — the row schedule has no span loop to
+    hide the gather under. Both modes are bitwise-identical;
     the knob only moves WHEN the touched rows are read. ``op='T'`` has no
     gather (X enters slot-permuted), so the knob is validated and ignored.
 
@@ -912,14 +902,16 @@ def spmm_row_distributed(sharded: ShardedSellCS, x: jax.Array, mesh: Mesh,
     x2, squeeze, k, kt, x_pad, use_pallas, maxis, pm, compact = _prep(
         sharded, x, mesh, axis, impl, k_tile, "row", model_axis, compact_x,
         op)
-    gmode = _resolve_gather(gather, compact)
+    _resolve_gather(gather, compact)      # validated; one gather here
     if sharded.nnz == 0:
         y = jnp.zeros((n if op == "T" else m, k),
                       _out_dtype(sharded, x2, use_pallas))
         return y[:, 0] if squeeze else y
     interpret = impl == "pallas_interpret"
+    # with one model shard the k-tile padding columns stop at the kernel:
+    # the fixup and the unpermute move the true k only
+    k_keep = k if pm == 1 else x_pad.shape[1] // pm
     if op == "T":
-        k_keep = k if pm == 1 else x_pad.shape[1] // pm
         n_eff = int(sharded.col_map.shape[1]) if compact else n
 
         def local_t(data, cols, slice_of, offs, x_loc):
@@ -935,12 +927,12 @@ def spmm_row_distributed(sharded: ShardedSellCS, x: jax.Array, mesh: Mesh,
                 return jax.lax.psum(y_loc[:, :k_keep], axis)
 
         with span("spmm/mesh"):
-            yb = maybe_block(shard_map(
+            yb = maybe_block(jax.shard_map(
                 local_t, mesh=mesh,
                 in_specs=(P(axis, None, None), P(axis, None, None),
                           P(axis, None), P(axis), P(None, maxis)),
                 out_specs=P(axis, maxis) if compact else P(None, maxis),
-                check_vma=False if use_pallas else None)(
+                check_vma=not use_pallas)(
                     sharded.data, sharded.cols, sharded.slice_of,
                     sharded.slice_offset, x_pad))
         with span("spmm/fixup"):
@@ -949,48 +941,33 @@ def spmm_row_distributed(sharded: ShardedSellCS, x: jax.Array, mesh: Mesh,
                     yb, sharded.col_map, sharded.n_touched, n, k, squeeze))
             y = yb[:n, :k]
             return maybe_block(y[:, 0] if squeeze else y)
-    if compact and gmode == "fused":
-        # the full X rides the mesh replicated and the kernel gathers
-        # through col_map in its own prefetch — no slab materializes
-        def local(data, cols, slice_of, cmap, x_loc):
-            with span("spmm/kernel"):
-                return _local_slots(data, cols, slice_of, x_loc,
-                                    num_slices=Sp, chunk=C,
-                                    use_pallas=use_pallas, k_tile=kt,
-                                    interpret=interpret, col_map=cmap[0])
-
-        in_specs = (P(axis, None, None), P(axis, None, None),
-                    P(axis, None), P(axis, None), P(None, maxis))
-        args = (sharded.data, sharded.cols, sharded.slice_of,
-                sharded.col_map, x_pad)
+    if compact:
+        # up-front gather ("overlap" degenerates here: no span loop)
+        with span("spmm/gather_x"):
+            x_feed = maybe_block(_gather_x(x_pad, sharded.col_map))
+        x_spec = P(axis, None, maxis)
     else:
-        if compact:
-            # up-front gather ("overlap" degenerates here: no span loop)
-            with span("spmm/gather_x"):
-                x_feed = maybe_block(_gather_x(x_pad, sharded.col_map))
-            x_spec = P(axis, None, maxis)
-        else:
-            x_feed, x_spec = x_pad, P(None, maxis)
+        x_feed, x_spec = x_pad, P(None, maxis)
 
-        def local(data, cols, slice_of, x_loc):
-            with span("spmm/kernel"):
-                return _local_slots(data, cols, slice_of,
-                                    x_loc[0] if compact else x_loc,
-                                    num_slices=Sp, chunk=C,
-                                    use_pallas=use_pallas, k_tile=kt,
-                                    interpret=interpret)
+    def local(data, cols, slice_of, x_loc):
+        with span("spmm/kernel"):
+            return _local_slots(data, cols, slice_of,
+                                x_loc[0] if compact else x_loc,
+                                num_slices=Sp, chunk=C,
+                                use_pallas=use_pallas, k_tile=kt,
+                                interpret=interpret)[:, :k_keep]
 
-        in_specs = (P(axis, None, None), P(axis, None, None),
-                    P(axis, None), x_spec)
-        args = (sharded.data, sharded.cols, sharded.slice_of, x_feed)
+    in_specs = (P(axis, None, None), P(axis, None, None),
+                P(axis, None), x_spec)
+    args = (sharded.data, sharded.cols, sharded.slice_of, x_feed)
 
     # pallas_call has no replication rule inside shard_map — skip the check
     with span("spmm/mesh"):
-        yb = maybe_block(shard_map(
+        yb = maybe_block(jax.shard_map(
             local, mesh=mesh,
             in_specs=in_specs,
             out_specs=P(axis, maxis),
-            check_vma=False if use_pallas else None)(*args))
+            check_vma=not use_pallas)(*args))
     with span("spmm/fixup"):
         yb = yb.reshape(ndev, Sp * C, -1)
         # shard p owns global slices [slice_offset[p], slice_offset[p+1]);
@@ -1072,10 +1049,8 @@ def spmm_merge_distributed(sharded: ShardedSellCS, x: jax.Array, mesh: Mesh,
     per-span touched split (``_ChunkSpan.sub``/``col_map``) — the span
     slabs have no cross-span data dependency, so span ``i+1``'s gather
     runs under span ``i``'s kernel/psum, the same overlap the pipelined
-    fixup already exploits. ``"fused"`` feeds the full X and lets the
-    kernel index it through ``col_map`` in its scalar prefetch — no slab
-    at all. All modes are bitwise-identical (the gather only re-indexes X
-    rows; untouched slab positions are read only by data == 0 padding
+    fixup already exploits. Both modes are bitwise-identical (the gather
+    only re-indexes X rows; untouched slab positions are read only by data == 0 padding
     lanes); the knob moves WHEN the touched rows are read, and the
     roofline prices the exposed seconds of each choice
     (``spmm_distributed_gather_s``). ``op='T'`` has no gather, so the
@@ -1152,13 +1127,13 @@ def spmm_merge_distributed(sharded: ShardedSellCS, x: jax.Array, mesh: Mesh,
         nspan = len(args[0])
         blk = tuple(P(axis, None, None) for _ in range(nspan))
         with span("spmm/mesh"):
-            yb = maybe_block(shard_map(
+            yb = maybe_block(jax.shard_map(
                 local_t, mesh=mesh,
                 in_specs=(blk, blk,
                           tuple(P(axis, None) for _ in range(nspan)),
                           P(None, maxis)),
                 out_specs=P(axis, maxis) if compact else P(None, maxis),
-                check_vma=False if use_pallas else None)(
+                check_vma=not use_pallas)(
                     *args, x_pad))
         with span("spmm/fixup"):
             if compact:
@@ -1168,52 +1143,36 @@ def spmm_merge_distributed(sharded: ShardedSellCS, x: jax.Array, mesh: Mesh,
             return maybe_block(y[:, 0] if squeeze else y)
 
     if nc == 1:
-        if compact and gmode == "fused":
-            def local(data, cols, slice_of, cmap, x_loc):
-                with span("spmm/kernel"):
-                    y_loc = _local_slots(data, cols, slice_of, x_loc,
-                                         num_slices=S, chunk=C,
-                                         use_pallas=use_pallas, k_tile=kt,
-                                         interpret=interpret,
-                                         col_map=cmap[0])
-                with span("spmm/psum"):
-                    return jax.lax.psum(y_loc[:, :k_keep], axis)
-
-            in_specs = (P(axis, None, None), P(axis, None, None),
-                        P(axis, None), P(axis, None), P(None, maxis))
-            args = (sharded.data, sharded.cols, sharded.slice_of,
-                    sharded.col_map, x_pad)
+        if compact:
+            # up-front gather ("overlap" degenerates: no span loop)
+            with span("spmm/gather_x"):
+                x_feed = maybe_block(_gather_x(x_pad, sharded.col_map))
+            x_spec = P(axis, None, maxis)
         else:
-            if compact:
-                # up-front gather ("overlap" degenerates: no span loop)
-                with span("spmm/gather_x"):
-                    x_feed = maybe_block(_gather_x(x_pad, sharded.col_map))
-                x_spec = P(axis, None, maxis)
-            else:
-                x_feed, x_spec = x_pad, P(None, maxis)
+            x_feed, x_spec = x_pad, P(None, maxis)
 
-            def local(data, cols, slice_of, x_loc):
-                with span("spmm/kernel"):
-                    y_loc = _local_slots(data, cols, slice_of,
-                                         x_loc[0] if compact else x_loc,
-                                         num_slices=S, chunk=C,
-                                         use_pallas=use_pallas, k_tile=kt,
-                                         interpret=interpret)
-                # carry-out fixup on the data axis ONLY: model shards own
-                # disjoint Y columns and never enter the collective
-                with span("spmm/psum"):
-                    return jax.lax.psum(y_loc[:, :k_keep], axis)
+        def local(data, cols, slice_of, x_loc):
+            with span("spmm/kernel"):
+                y_loc = _local_slots(data, cols, slice_of,
+                                     x_loc[0] if compact else x_loc,
+                                     num_slices=S, chunk=C,
+                                     use_pallas=use_pallas, k_tile=kt,
+                                     interpret=interpret)
+            # carry-out fixup on the data axis ONLY: model shards own
+            # disjoint Y columns and never enter the collective
+            with span("spmm/psum"):
+                return jax.lax.psum(y_loc[:, :k_keep], axis)
 
-            in_specs = (P(axis, None, None), P(axis, None, None),
-                        P(axis, None), x_spec)
-            args = (sharded.data, sharded.cols, sharded.slice_of, x_feed)
+        in_specs = (P(axis, None, None), P(axis, None, None),
+                    P(axis, None), x_spec)
+        args = (sharded.data, sharded.cols, sharded.slice_of, x_feed)
 
         with span("spmm/mesh"):
-            y_slots = maybe_block(shard_map(
+            y_slots = maybe_block(jax.shard_map(
                 local, mesh=mesh,
                 in_specs=in_specs,
                 out_specs=P(None, maxis),
-                check_vma=False if use_pallas else None)(*args))
+                check_vma=not use_pallas)(*args))
         with span("spmm/fixup"):
             return maybe_block(_unpermute(sharded, y_slots, k, squeeze))
 
@@ -1230,15 +1189,15 @@ def spmm_merge_distributed(sharded: ShardedSellCS, x: jax.Array, mesh: Mesh,
                  tuple(sp.cols for sp in spans),
                  tuple(sp.slice_of for sp in spans))
 
-    def _span_kernel(data, cols, slice_of, x_loc, s0, ns, col_map=None):
+    def _span_kernel(data, cols, slice_of, x_loc, s0, ns):
         if use_pallas:
             return sellcs_slots_chunk(
                 data[0], cols[0], slice_of[0], x_loc,
                 slice_start=s0, num_slices=ns, chunk=C, k_tile=kt,
-                interpret=interpret, col_map=col_map)
+                interpret=interpret)
         return sellcs_slots_chunk_ref(
             data[0], cols[0], slice_of[0], x_loc,
-            slice_start=s0, num_slices=ns, chunk=C, col_map=col_map)
+            slice_start=s0, num_slices=ns, chunk=C)
 
     if compact and gmode == "overlap" and \
             all(sp.sub is not None for sp in spans):
@@ -1270,22 +1229,6 @@ def spmm_merge_distributed(sharded: ShardedSellCS, x: jax.Array, mesh: Mesh,
                     P(None, maxis))
         args = span_args + (tuple(sp.sub for sp in spans),
                             tuple(sp.col_map for sp in spans), x_pad)
-    elif compact and gmode == "fused":
-        def local(datas, colss, sos, cmap, x_loc):
-            cm0 = cmap[0]
-            outs = []
-            for (s0, ns), data, cols, slice_of in zip(meta, datas, colss,
-                                                      sos):
-                with span("spmm/kernel"):
-                    y_c = _span_kernel(data, cols, slice_of, x_loc, s0, ns,
-                                       col_map=cm0)
-                with span("spmm/psum"):
-                    outs.append(jax.lax.psum(y_c[:, :k_keep], axis))
-            return jnp.concatenate(outs, axis=0)
-
-        in_specs = (span_spec, span_spec, so_spec, P(axis, None),
-                    P(None, maxis))
-        args = span_args + (plan_map, x_pad)
     else:
         if compact:
             # the spans' cols live in the chunk plan's index space, not
@@ -1317,10 +1260,10 @@ def spmm_merge_distributed(sharded: ShardedSellCS, x: jax.Array, mesh: Mesh,
         args = span_args + (x_feed,)
 
     with span("spmm/mesh"):
-        y_slots = maybe_block(shard_map(
+        y_slots = maybe_block(jax.shard_map(
             local, mesh=mesh,
             in_specs=in_specs,
             out_specs=P(None, maxis),
-            check_vma=False if use_pallas else None)(*args))
+            check_vma=not use_pallas)(*args))
     with span("spmm/fixup"):
         return maybe_block(_unpermute(sharded, y_slots, k, squeeze))

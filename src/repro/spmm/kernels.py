@@ -1,72 +1,86 @@
 """Tiled Pallas SpMM kernels — CSR (merge-path), blocked (TiledSparse) and
 SELL-C-σ, each with a column-block (k-tile) grid dimension.
 
-Every kernel streams the matrix exactly once per k-tile and keeps an
-``[·, KT]`` slab of X and Y VMEM-resident, so the arithmetic intensity of a
-pass grows KT-fold over SpMV — the one lever that moves a memory-bound
-SpMV up the roofline (paper §1; Schubert/Hager/Fehske). The k-tile is the
-*leading, parallel* grid dimension: k-tiles touch disjoint X/Y columns, so
-megacore (or a future multi-device grid) can split them freely, while the
-matrix-stream dimension stays "arbitrary" (sequential accumulate).
+The SELL-C-σ forward kernel (``sellcs_slots``) is the one that lowers on
+Mosaic and serves the TPU path, on one device and in every distributed
+schedule. It keeps X and the slot output in HBM: each grid step DMAs the
+X rows its width-rows name into VMEM and adds finished slices into Y, so
+its VMEM footprint is fixed by the k-tile and matrices far larger than
+VMEM run. The k-tile is the *leading, parallel* grid dimension: k-tiles
+touch disjoint X/Y columns, while the matrix stream stays "arbitrary"
+(sequential accumulate).
 
-``choose_k_tile`` picks KT from the roofline model in ``repro.roofline``:
-grow KT until either the X/Y slabs stop fitting the VMEM budget or the
-modelled intensity crosses the ridge (beyond which more reuse buys
-nothing).
+The CSR merge and blocked kernels, and the transpose kernel beyond a
+VMEM-sized n, keep whole ``[n, KT]`` slabs VMEM-resident and gather with an
+in-kernel ``jnp.take``, which Mosaic refuses: they run in interpret mode
+only, and raise for ``interpret=False``.
+
+``choose_k_tile`` picks the widest lane-multiple KT whose VMEM working set
+fits the budget: every extra k-tile re-streams the matrix and re-issues its
+gathers. The interpret-only kernels take all k columns as one tile.
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
 from repro.core.convert import VMEM_BUDGET_BYTES
 from repro.core.formats import CSR
 from repro.kernels import merge_spmv as _merge
-from repro.kernels.tiling import TILE_C, TILE_R, TiledSparse
-from repro.roofline.analysis import csr_stream_bytes, ridge_intensity
+from repro.kernels.tiling import (TILE_C, TILE_R, TiledSparse,
+                                  interpret_only)
 from .sellcs import SellCS
 
 LANE = 128
-W_TILE = 8          # width-rows per SELL-C-σ grid step (sublane-sized)
+W_TILE = 8          # width-rows per grid step of the transpose kernel
+GATHER_W_TILE = 32  # width-rows per grid step of the forward gather kernel
+SO_BLOCK = 1024     # slice ids per SMEM block (XLA's int32 vector tile)
 
 
-def choose_k_tile(shape: Tuple[int, int], k: int, *,
-                  nnz: Optional[int] = None, dtype_bytes: int = 4,
+def _lanes(kt: int) -> int:
+    """VMEM lanes a row of ``kt`` 32-bit values occupies (lane padding)."""
+    return -(-kt // LANE) * LANE
+
+
+def sellcs_vmem_bytes(k_tile: int, chunk: int = 128) -> int:
+    """VMEM working set of ``sellcs_slots`` at a lane-multiple ``k_tile``:
+    the two gather slots, the slice accumulator and its read-back buffer,
+    and the double-buffered data block (its rows lane-padded)."""
+    kl = k_tile * 4
+    return (2 * GATHER_W_TILE * chunk * kl + 2 * chunk * kl
+            + 2 * GATHER_W_TILE * _lanes(chunk) * 4)
+
+
+def choose_k_tile(k: int, *, chunk: int = 128,
                   vmem_budget: int = VMEM_BUDGET_BYTES) -> int:
-    """Roofline-guided k-tile: the largest KT <= k such that
+    """The k-tile of ``sellcs_slots`` for a k-column multiply: the widest
+    lane multiple, up to k rounded up to a lane, whose working set fits
+    ``vmem_budget``; never below one lane (HBM rows are DMA'd lane-aligned,
+    so a narrower tile costs the same). Independent of the matrix shape —
+    X and Y stay in HBM. Callers pad X to a multiple of it."""
+    kt = _lanes(max(int(k), 1))
+    while kt > LANE and sellcs_vmem_bytes(kt, chunk) > vmem_budget:
+        kt -= LANE
+    return kt
 
-    (a) the [n_pad, KT] X-slab and [m_pad, KT] Y-slab fit half the VMEM
-        budget (the other half double-buffers the matrix stream), and
-    (b) (given nnz) the modelled intensity at KT does not overshoot the
-        ridge by more than one lane group — past the ridge the kernel is
-        compute-bound and larger KT only bloats VMEM.
 
-    KT is rounded down to a lane multiple once it exceeds one lane, and is
-    always >= 1.
-    """
-    m, n = shape
-    mp = -(-max(m, 1) // TILE_R) * TILE_R
-    np_ = -(-max(n, 1) // LANE) * LANE
-    slab_rows = (mp + np_) * dtype_bytes
-    kt = max(min(k, (vmem_budget // 2) // max(slab_rows, 1)), 1)
-    if nnz:
-        # smallest KT whose intensity reaches the ridge
-        ridge = ridge_intensity()
-        mat_bytes = csr_stream_bytes(nnz, m, dtype_bytes)
-        vec_bytes = (m + n) * dtype_bytes
-        denom = 2.0 * nnz - ridge * vec_bytes
-        if denom > 0:
-            kt_ridge = int(ridge * mat_bytes / denom) + 1
-            kt = min(kt, max(kt_ridge, 1))
-    if kt >= LANE:
-        kt = (kt // LANE) * LANE
-    return max(min(kt, k), 1)
+def resolve_impl(impl: str, mat, op: str = "N") -> str:
+    """The implementation that runs for ``impl`` on ``mat``: ``"auto"``
+    is the Mosaic kernel on a TPU backend where one lowers — the SELL-C-σ
+    forward pass of a general matrix — and the XLA reference everywhere
+    else. Explicit impls pass through (a kernel without a Mosaic lowering
+    then raises instead of running something else)."""
+    if impl != "auto":
+        return impl
+    if (jax.default_backend() == "tpu" and isinstance(mat, SellCS)
+            and mat.structure == "general" and op == "N"):
+        return "pallas"
+    return "ref"
 
 
 def _pad_k(x: jax.Array, kt: int) -> jax.Array:
@@ -114,10 +128,11 @@ def tiled_spmm(ts: TiledSparse, x: jax.Array, *,
     """Y = A @ X over the dense-mini-tile stream, grid = (k_tiles, tile
     batches). Serves every blocked paper format (their TPU compute form is
     TiledSparse) and is the k-generalization of kernels.bsr_spmv."""
+    interpret_only("tiled_spmm", interpret)
     m, n = ts.shape
     mp, np_ = ts.padded_shape()
     k = x.shape[1]
-    kt = k_tile or choose_k_tile(ts.shape, k, nnz=ts.nnz)
+    kt = k_tile or k
     x_pad = jnp.zeros((np_, k), x.dtype).at[:n].set(x)
     x_pad = _pad_k(x_pad, kt)
     nk = x_pad.shape[1] // kt
@@ -148,7 +163,7 @@ def tiled_spmm(ts: TiledSparse, x: jax.Array, *,
         functools.partial(_tiled_kernel, tiles_per_step=TB),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((mp, x_pad.shape[1]), jnp.float32),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(tile_rows, tile_cols, tiles, x_pad)
@@ -179,6 +194,7 @@ def _merge_kernel(cols_ref, vals_ref, seg_ref, x_ref, out_ref, *,
 def _merge_spmm_partials(plan_cols, plan_vals, plan_seg, x_pad, *,
                          r_width: int, k_tile: int,
                          interpret: bool = False):
+    interpret_only("csr_spmm", interpret)
     P, D = plan_cols.shape
     np_ = x_pad.shape[0]
     nk = x_pad.shape[1] // k_tile
@@ -217,7 +233,7 @@ def csr_spmm(csr: CSR, x: jax.Array, *,
         if num_spans is None:
             num_spans = _merge.default_num_spans(m, csr.nnz)
         plan = _merge.merge_plan(csr, num_spans)
-    kt = k_tile or choose_k_tile(csr.shape, k, nnz=csr.nnz)
+    kt = k_tile or k
     np_ = -(-n // LANE) * LANE
     x_pad = jnp.zeros((np_, k), x.dtype).at[:n].set(x)
     x_pad = _pad_k(x_pad, kt)
@@ -228,132 +244,205 @@ def csr_spmm(csr: CSR, x: jax.Array, *,
 
 
 # --------------------------------------------------------------------------
-# SELL-C-σ SpMM, k-tiled grid
+# SELL-C-σ SpMM, k-tiled grid, X gathered from HBM by DMA
 # --------------------------------------------------------------------------
-def _sellcs_kernel(slice_of_ref,                  # scalar prefetch (SMEM)
-                   data_ref, cols_ref, x_ref,     # VMEM in
-                   y_ref,                         # VMEM out (revisited)
-                   *, w_tile: int, chunk: int):
+def _sellcs_kernel(so_ref, nl_ref, nln_ref,            # SMEM per-row ids
+                   cols0_ref, colsn_ref,               # SMEM (WT, C)
+                   data_ref,                           # VMEM (WT, C)
+                   x_hbm, y_init, y_hbm,               # HBM (ANY)
+                   xbuf, acc, tmp, cur, cnt, gsem, ysem,
+                   *, w_tile: int, chunk: int, k_tile: int, so_block: int,
+                   rows: int):
+    """One grid step = ``w_tile`` width-rows of one k-tile.
+
+    The X rows a step names are DMA'd from HBM into ``xbuf[slot]``, one
+    row per lane up to the width-row's last stored lane (``nl``: σ-sorted
+    rows put the padding at the end of each width-row, so the walk skips
+    it); lanes with ``data == 0`` contribute exact zeros whatever their
+    buffer holds. Step ``g`` issues step ``g+1``'s
+    gathers (``colsn`` is the next block, in SMEM) before it
+    waits for its own, so the DMA latency hides under the next issue loop.
+    Products accumulate per slice in ``acc``; when the slice id changes the
+    finished slice is added into the HBM output (read-modify-write), so
+    only one ``(C, KT)`` block of Y is ever resident and a stream that
+    revisits a slice (padding width-rows aimed at slice 0) stays exact.
+    """
+    j = pl.program_id(0)
     g = pl.program_id(1)
+    last = pl.num_programs(1) - 1
+    slot = g % 2
+    c0 = j * k_tile
+
+    per = so_block // w_tile                  # steps per SMEM id block
+
+    def issue(cols_ref, lens_ref, sl, step):
+        base = (step % per) * w_tile
+
+        def row(w, n):
+            width = jnp.where(step * w_tile + w < rows, lens_ref[base + w], 0)
+
+            def lane(l, c):
+                pltpu.make_async_copy(
+                    x_hbm.at[pl.ds(cols_ref[w, l], 1), pl.ds(c0, k_tile)],
+                    xbuf.at[sl, w, pl.ds(l, 1)], gsem.at[sl]).start()
+                return c
+            jax.lax.fori_loop(0, width, lane, 0)
+            return n + width
+        cnt[sl] = jax.lax.fori_loop(0, w_tile, row, jnp.int32(0))
 
     @pl.when(g == 0)
-    def _init():
-        y_ref[...] = jnp.zeros_like(y_ref)
+    def _first():
+        cur[0] = -1
+        issue(cols0_ref, nl_ref, 0, 0)
 
-    cols = cols_ref[...]                                       # (WT, C)
-    xs = jnp.take(x_ref[...], cols.reshape(-1), axis=0,
-                  mode="clip")                                 # (WT*C, KT)
-    kt = xs.shape[1]
-    contrib = (data_ref[...].astype(jnp.float32).reshape(-1)[:, None]
-               * xs.astype(jnp.float32)
-               ).reshape(w_tile, chunk, kt)                    # (WT, C, KT)
+    @pl.when(g < last)
+    def _prefetch():
+        issue(colsn_ref, nln_ref, 1 - slot, g + 1)
 
-    def body(w, _):
-        s = slice_of_ref[g * w_tile + w]
-        cur = y_ref[pl.ds(s * chunk, chunk), :]
-        y_ref[pl.ds(s * chunk, chunk), :] = cur + contrib[w]
-        return _
+    def wait(_, c):
+        pltpu.make_async_copy(x_hbm.at[pl.ds(0, 1), pl.ds(c0, k_tile)],
+                              xbuf.at[slot, 0, pl.ds(0, 1)],
+                              gsem.at[slot]).wait()
+        return c
 
-    jax.lax.fori_loop(0, w_tile, body, None)
+    jax.lax.fori_loop(0, cnt[slot], wait, 0)
 
+    d_t = data_ref[...].astype(jnp.float32).T                # (C, WT)
+    for w in range(w_tile):
+        d = d_t[:, w:w + 1]                                   # (C, 1)
+        live = jnp.logical_and(d != 0, g * w_tile + w < rows)
+        xbuf[slot, w] = jnp.where(live, d * xbuf[slot, w], 0.0)
 
-def _sellcs_fused_kernel(slice_of_ref, col_map_ref,  # scalar prefetch (SMEM)
-                         data_ref, cols_ref, x_ref,  # VMEM in
-                         y_ref,                      # VMEM out (revisited)
-                         *, w_tile: int, chunk: int):
-    """``_sellcs_kernel`` with the compact-X gather fused into the stream:
-    stored ``cols`` are compact ids, ``col_map`` (riding the scalar prefetch
-    next to ``slice_of``) maps them to rows of the full padded X, so no
-    up-front slab materialization happens outside the kernel."""
-    g = pl.program_id(1)
+    def flush(s):
+        dst = y_hbm.at[pl.ds(s * chunk, chunk), pl.ds(c0, k_tile)]
+        cp = pltpu.make_async_copy(dst, tmp, ysem)
+        cp.start()
+        cp.wait()
+        tmp[...] += acc[...]
+        cp = pltpu.make_async_copy(tmp, dst, ysem)
+        cp.start()
+        cp.wait()
 
-    @pl.when(g == 0)
-    def _init():
-        y_ref[...] = jnp.zeros_like(y_ref)
+    so_base = (g % per) * w_tile
 
-    cols = cols_ref[...]                                       # (WT, C)
-    gcols = jnp.take(col_map_ref[...], cols.reshape(-1),
-                     mode="clip")                              # (WT*C,)
-    xs = jnp.take(x_ref[...], gcols, axis=0, mode="clip")      # (WT*C, KT)
-    kt = xs.shape[1]
-    contrib = (data_ref[...].astype(jnp.float32).reshape(-1)[:, None]
-               * xs.astype(jnp.float32)
-               ).reshape(w_tile, chunk, kt)                    # (WT, C, KT)
+    def accumulate(w, c):
+        s = so_ref[so_base + w]
 
-    def body(w, _):
-        s = slice_of_ref[g * w_tile + w]
-        cur = y_ref[pl.ds(s * chunk, chunk), :]
-        y_ref[pl.ds(s * chunk, chunk), :] = cur + contrib[w]
-        return _
+        @pl.when(jnp.logical_and(s != cur[0], g * w_tile + w < rows))
+        def _switch():
+            @pl.when(cur[0] >= 0)
+            def _():
+                flush(cur[0])
+            acc[...] = jnp.zeros_like(acc)
+            cur[0] = s
 
-    jax.lax.fori_loop(0, w_tile, body, None)
+        acc[...] += xbuf[slot, w]
+        return c
+
+    jax.lax.fori_loop(0, w_tile, accumulate, 0)
+
+    @pl.when(jnp.logical_and(g == last, cur[0] >= 0))
+    def _last():
+        flush(cur[0])
 
 
 @functools.partial(jax.jit, static_argnames=("num_slices", "chunk",
                                              "k_tile", "interpret"))
 def sellcs_slots(data: jax.Array, cols: jax.Array, slice_of: jax.Array,
                  x_pad: jax.Array, *, num_slices: int, chunk: int,
-                 k_tile: int, interpret: bool = False,
-                 col_map: jax.Array | None = None) -> jax.Array:
+                 k_tile: int, interpret: bool = False) -> jax.Array:
     """Raw-array slot-space SpMM over a SELL-C-σ width-row stream.
 
-    Accumulates into row slots ``[num_slices * chunk, Kp]`` without applying
-    any row permutation. This is the shard-local compute of the distributed
-    schedules (``repro.spmm.distributed``): a shard's slice stream is just a
-    shorter width-row stream with its own ``slice_of``/``num_slices``, so
-    the same k-tiled Pallas kernel serves one device or a mesh body.
+    Accumulates into row slots ``[num_slices * chunk, Kp]`` (float32)
+    without applying any row permutation. This is the shard-local compute
+    of the distributed schedules (``repro.spmm.distributed``): a shard's
+    slice stream is just a shorter width-row stream with its own
+    ``slice_of``/``num_slices``, so the same kernel serves one device or a
+    mesh body.
 
-    With ``col_map`` (int32[Ntc], LANE-padded, padding pointing at row 0)
-    the stored ``cols`` are compact ids and the gather into the full
-    ``x_pad`` fuses into the kernel via a second scalar-prefetch operand —
-    the ``gather="fused"`` mode of the distributed multiplies.
+    X and Y stay in HBM: the kernel gathers the X rows each width-row names
+    by DMA and adds each finished slice into Y, so its VMEM use depends on
+    ``k_tile`` alone, never on n or m. ``Kp`` (the width of ``x_pad``) is a
+    multiple of ``k_tile``, which on Mosaic is a lane multiple
+    (``choose_k_tile``): HBM rows are DMA'd whole lanes at a time.
     """
+    Kp = x_pad.shape[1]
+    if Kp % k_tile:
+        raise ValueError(f"x_pad width {Kp} is not a multiple of k_tile "
+                         f"{k_tile}; pad it (choose_k_tile, _pad_k)")
+    if not interpret and k_tile % LANE:
+        raise ValueError(f"k_tile {k_tile} is not a multiple of {LANE}: "
+                         "Mosaic DMAs HBM rows whole lanes at a time")
     C = chunk
-    S = num_slices
+    WT = GATHER_W_TILE
     W = data.shape[0]
-    Wp = max(-(-W // W_TILE) * W_TILE, W_TILE)
-    if Wp != W:
-        pad = Wp - W
+    # XLA tiles an int32 vector in HBM by 1024, and a 1-D SMEM block must
+    # match: one block of slice ids serves SO_BLOCK // WT steps (interpret
+    # mode has no tiling; a lane keeps its CPU runs short). The last block
+    # may run past W — the kernel masks rows >= W — so only a stream
+    # shorter than one block is padded
+    so_block = LANE if interpret else SO_BLOCK
+    if W < so_block:
+        pad = so_block - W
         data = jnp.concatenate([data, jnp.zeros((pad, C), data.dtype)])
         cols = jnp.concatenate([cols, jnp.zeros((pad, C), cols.dtype)])
         # padding width-rows carry data == 0; aim them at slice 0 harmlessly
         slice_of = jnp.concatenate(
             [slice_of, jnp.zeros((pad,), slice_of.dtype)])
-
-    np_, Kp = x_pad.shape
+    cols = cols.astype(jnp.int32)
+    slice_of = slice_of.astype(jnp.int32)
+    lane = jnp.arange(1, C + 1, dtype=jnp.int32)
+    nlive = jnp.max(jnp.where(data != 0, lane, 0), axis=1)
+    x_pad = x_pad.astype(jnp.float32)
     nk = Kp // k_tile
+    G = -(-max(W, so_block) // WT)
+    per = so_block // WT
+    n_blocks = -(-max(W, so_block) // so_block)
+    smem = pltpu.MemorySpace.SMEM
+    nxt = lambda j, g: (jnp.minimum(g + 1, G - 1), 0)
+    ids = lambda j, g: (g // per,)
+    ids_nxt = lambda j, g: (jnp.minimum((g + 1) // per, n_blocks - 1),)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2 if col_map is not None else 1,
-        grid=(nk, Wp // W_TILE),
+        num_scalar_prefetch=0,
+        grid=(nk, G),
         in_specs=[
-            pl.BlockSpec((W_TILE, C), lambda j, g, *_: (g, 0)),
-            pl.BlockSpec((W_TILE, C), lambda j, g, *_: (g, 0)),
-            pl.BlockSpec((np_, k_tile), lambda j, g, *_: (0, j)),
+            pl.BlockSpec((so_block,), ids, memory_space=smem),
+            pl.BlockSpec((so_block,), ids, memory_space=smem),
+            pl.BlockSpec((so_block,), ids_nxt, memory_space=smem),
+            pl.BlockSpec((WT, C), lambda j, g: (0, 0), memory_space=smem),
+            pl.BlockSpec((WT, C), nxt, memory_space=smem),
+            pl.BlockSpec((WT, C), lambda j, g: (g, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((S * C, k_tile), lambda j, g, *_: (0, j)),
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        scratch_shapes=[
+            pltpu.VMEM((2, WT, C, k_tile), jnp.float32),     # gathered X
+            pltpu.VMEM((C, k_tile), jnp.float32),            # slice acc
+            pltpu.VMEM((C, k_tile), jnp.float32),            # Y read-back
+            pltpu.SMEM((1,), jnp.int32),                     # open slice
+            pltpu.SMEM((2,), jnp.int32),                     # DMAs in flight
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SemaphoreType.DMA(()),
+        ],
     )
-    if col_map is not None:
-        kernel = functools.partial(_sellcs_fused_kernel,
-                                   w_tile=W_TILE, chunk=C)
-        operands = (slice_of, col_map, data, cols, x_pad)
-    else:
-        kernel = functools.partial(_sellcs_kernel, w_tile=W_TILE, chunk=C)
-        operands = (slice_of, data, cols, x_pad)
+    y0 = jnp.zeros((num_slices * C, Kp), jnp.float32)
     return pl.pallas_call(
-        kernel,
+        functools.partial(_sellcs_kernel, w_tile=WT, chunk=C,
+                          k_tile=k_tile, so_block=so_block, rows=W),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S * C, Kp), jnp.float32),
-        compiler_params=tpu_compiler_params(
+        out_shape=jax.ShapeDtypeStruct((num_slices * C, Kp), jnp.float32),
+        input_output_aliases={7: 0},
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(*operands)
+    )(slice_of, nlive, nlive, cols[:WT], cols, data, x_pad, y0)
 
 
 def sellcs_slots_chunk(data: jax.Array, cols: jax.Array,
                        slice_of: jax.Array, x_pad: jax.Array, *,
                        slice_start: int, num_slices: int, chunk: int,
-                       k_tile: int, interpret: bool = False,
-                       col_map: jax.Array | None = None) -> jax.Array:
+                       k_tile: int, interpret: bool = False) -> jax.Array:
     """``sellcs_slots`` over one *chunk sub-stream* of the slice stream.
 
     The chunked distributed merge schedule (``repro.spmm.distributed``)
@@ -367,8 +456,7 @@ def sellcs_slots_chunk(data: jax.Array, cols: jax.Array,
     local = jnp.clip(slice_of.astype(jnp.int32) - slice_start, 0,
                      max(num_slices - 1, 0))
     return sellcs_slots(data, cols, local, x_pad, num_slices=num_slices,
-                        chunk=chunk, k_tile=k_tile, interpret=interpret,
-                        col_map=col_map)
+                        chunk=chunk, k_tile=k_tile, interpret=interpret)
 
 
 def _sellcs_spmm_slots(sc: SellCS, x_pad: jax.Array, *, k_tile: int,
@@ -442,6 +530,10 @@ def sellcs_slots_t(data: jax.Array, cols: jax.Array, slice_of: jax.Array,
 
     n_pad = -(-max(n_out, 1) // LANE) * LANE
     SC, Kp = x_slots.shape
+    if 2 * (SC + n_pad) * _lanes(k_tile) * 4 > VMEM_BUDGET_BYTES:
+        # both slabs stay VMEM-resident, and the one-hot scatter is
+        # (C, n_pad) per width-row
+        interpret_only(f"sellcs_slots_t at n={n_out}, {SC} slots", interpret)
     nk = Kp // k_tile
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -458,7 +550,7 @@ def sellcs_slots_t(data: jax.Array, cols: jax.Array, slice_of: jax.Array,
                           n_pad=n_pad),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_pad, Kp), jnp.float32),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(slice_of, data, cols, x_slots)
@@ -476,10 +568,11 @@ def _slot_x_pad(row_perm: jax.Array, x: jax.Array, m: int,
 
 def sellcs_spmm(sc: SellCS, x: jax.Array, *, k_tile: Optional[int] = None,
                 interpret: bool = False, op: str = "N") -> jax.Array:
-    """SELL-C-σ SpMM: each grid step broadcasts W_TILE width-vectors of the
-    slice stream against the VMEM-resident X slab — uniform work quanta
-    regardless of row-length skew (the σ-sorted answer to the paper's mawi
-    pathology), with the x-gather as the only irregular access.
+    """SELL-C-σ SpMM: each grid step multiplies GATHER_W_TILE
+    width-vectors of the slice stream by the X rows they name, gathered
+    from HBM — uniform work quanta regardless of row-length skew (the
+    σ-sorted answer to the paper's mawi pathology), with the x-gather as
+    the only irregular access.
 
     ``op='T'`` computes ``Y = A^T X`` (``X: [m, k]``) via the transpose
     kernel; symmetric one-triangle storage combines both passes over the
@@ -490,7 +583,7 @@ def sellcs_spmm(sc: SellCS, x: jax.Array, *, k_tile: Optional[int] = None,
         raise ValueError(f"op must be 'N' or 'T', got {op!r}")
     m, n = sc.shape
     k = x.shape[1]
-    kt = k_tile or choose_k_tile(sc.shape, k, nnz=sc.nnz)
+    kt = k_tile or choose_k_tile(k, chunk=sc.chunk)
     sym = sc.structure == "symmetric"
     if op == "T" and not sym:
         if sc.nnz == 0:
@@ -504,15 +597,15 @@ def sellcs_spmm(sc: SellCS, x: jax.Array, *, k_tile: Optional[int] = None,
     x_pad = _pad_k(x_pad, kt)
     if sc.nnz == 0:
         return jnp.zeros((m, k), jnp.float32)
+    # the k-tile padding columns stop here: the σ-unpermute moves k only
     y_slots = _sellcs_spmm_slots(sc, x_pad, k_tile=kt,
-                                 interpret=interpret)     # (S*C, Kp)
-    Kp = y_slots.shape[1]
-    y = jnp.zeros((m + 1, Kp), jnp.float32).at[sc.row_perm].add(y_slots)
+                                 interpret=interpret)[:, :k]   # (S*C, k)
+    y = jnp.zeros((m + 1, k), jnp.float32).at[sc.row_perm].add(y_slots)
     y = y[:m]
     if sym:
         xs = _slot_x_pad(sc.row_perm, x, m, kt)
         y = (y + sellcs_slots_t(sc.data, sc.cols, sc.slice_of, xs,
                                 n_out=n, chunk=sc.chunk, k_tile=kt,
-                                interpret=interpret)
-             - _pad_k(sc.diag[:, None] * x, kt))
-    return y[:m, :k]
+                                interpret=interpret)[:, :k]
+             - sc.diag[:, None] * x)
+    return y

@@ -68,14 +68,6 @@ def _pick_chunk(m: int, num_devices: int, default: int = 128) -> int:
     return c
 
 
-def _resolve_impl(impl: str) -> str:
-    """The serve convention: "auto" means the Pallas kernels on TPU and
-    the jnp reference everywhere else."""
-    if impl == "auto":
-        return "pallas" if jax.default_backend() == "tpu" else "ref"
-    return impl
-
-
 class RealizedPlan(NamedTuple):
     """One executable multiply plan: the resolved :class:`PlanSpec`, the
     execution-side matrix (a partitioned ``ShardedSellCS`` on a mesh, the
@@ -85,7 +77,7 @@ class RealizedPlan(NamedTuple):
     build seconds). Immutable — :meth:`SparseOperator.swap` installs a
     whole plan in one reference assignment."""
     spec: PlanSpec               # fully resolved (no None knobs on a mesh)
-    label: str                   # e.g. "sellcs+merge@4x2mesh/chunks=2"
+    label: str                   # e.g. "sellcs+merge@4x2mesh/chunks=2[pallas]"
     matrix: object               # what the multiply executes against
     local_matrix: object         # single-device form for sequential
                                  #   baselines (the pre-partition stream
@@ -93,7 +85,9 @@ class RealizedPlan(NamedTuple):
     multiply: Callable           # X -> Y, jitted where distributed
     eager: Optional[Callable]    # un-jitted X -> Y (mesh only) — the
                                  #   phase-profile pass --metrics runs
-    impl: str                    # resolved kernel impl ("ref"/"pallas")
+    impl: str                    # the impl the forward multiply runs
+                                 #   ("ref"/"pallas"/"pallas_interpret"),
+                                 #   resolved per format; also in label
     n_touched: Optional[float]   # mean touched columns per shard
                                  #   (compact_x plans only)
     model_s: Callable            # k -> roofline seconds for one k-RHS
@@ -420,6 +414,33 @@ def sparse_matmul(op: SparseOperator, x: jax.Array) -> jax.Array:
     return f(x)
 
 
+class _JitOver:
+    """``X -> fn(matrix, X)``, jitted with the matrix's arrays passed as
+    arguments. Closed over, they would be captured as constants: at
+    deployment size gigabytes embedded in every compiled flush (and a
+    compile that takes minutes)."""
+
+    def __init__(self, fn, matrix):
+        leaves, treedef = jax.tree_util.tree_flatten(matrix)
+        where = [i for i, leaf in enumerate(leaves)
+                 if isinstance(leaf, jax.Array)]
+
+        def call(arrays, X):
+            full = list(leaves)
+            for i, a in zip(where, arrays):
+                full[i] = a
+            return fn(jax.tree_util.tree_unflatten(treedef, full), X)
+
+        self._jitted = jax.jit(call)
+        self._arrays = [leaves[i] for i in where]
+
+    def __call__(self, X):
+        return self._jitted(self._arrays, X)
+
+    def lower(self, X):
+        return self._jitted.lower(self._arrays, X)
+
+
 def _realize_plan(coo: COO, stats: MatrixStats, spec: PlanSpec, *,
                   impl: str, k_hint: int, num_spmvs: int, feedback=None,
                   cache: Optional[_PlanCache] = None,
@@ -456,16 +477,20 @@ def _realize_single(coo, stats, spec, *, impl, k_hint, num_spmvs, t0,
     else:
         mat = convert(coo, algo)
     mat_bytes = _matrix_bytes_est(algo, stats)
+    from repro.spmm.kernels import resolve_impl
+    impl_r = resolve_impl(impl, mat)
 
     def multiply(X):
         from repro.spmm import spmm
-        return spmm(mat, X, impl=impl)
+        return spmm(mat, X, impl=impl_r)
 
     from repro.spmm.sellcs import SellCS as _SellCS
     if isinstance(mat, (_SellCS, COO)):
+        impl_t = resolve_impl(impl, mat, "T")
+
         def multiply_t(X):
             from repro.spmm import spmm
-            return spmm(mat, X, impl=impl, op="T")
+            return spmm(mat, X, impl=impl_t, op="T")
     else:
         # formats without a transpose path fall back to the immutable COO
         # source the operator already owns — correct, just unamortized
@@ -483,8 +508,8 @@ def _realize_single(coo, stats, spec, *, impl, k_hint, num_spmvs, t0,
 
     resolved = dataclasses.replace(spec, algorithm=algo,
                                    structure=structure)
-    return RealizedPlan(resolved, algo, mat, mat, multiply, None,
-                        _resolve_impl(impl), None, model_s,
+    return RealizedPlan(resolved, f"{algo}[{impl_r}]", mat, mat, multiply,
+                        None, impl_r, None, model_s,
                         time.perf_counter() - t0,
                         multiply_t=multiply_t)
 
@@ -531,7 +556,8 @@ def _realize_mesh(coo, stats, spec, *, impl, k_hint, num_spmvs, feedback,
             op_stats.sellcs_builds += 1
     elif op_stats is not None:
         op_stats.plan_cache_hits += 1
-    impl_r = _resolve_impl(impl)
+    from repro.spmm.kernels import resolve_impl
+    impl_r = resolve_impl(impl, sc)
     key = (schedule, pd, compact, structure)
     base = cache.partitions.get(key)
     if base is None:
@@ -563,24 +589,17 @@ def _mesh_plan(sharded, sc, stats, mesh, *, schedule, chunks, pd, pm,
     structure = getattr(sharded, "structure", "general")
     gx = gather if compact else None
     if schedule == "row":
-        eager = lambda X: spmm_row_distributed(sharded, X, mesh,
-                                               impl=impl_r, gather=gx)
-        eager_t = lambda X: spmm_row_distributed(sharded, X, mesh,
-                                                 impl=impl_r, op="T",
-                                                 gather=gx)
+        mult = lambda sh, X, op: spmm_row_distributed(
+            sh, X, mesh, impl=impl_r, op=op, gather=gx)
     else:
-        eager = lambda X: spmm_merge_distributed(sharded, X, mesh,
-                                                 impl=impl_r,
-                                                 num_chunks=chunks,
-                                                 gather=gx)
-        eager_t = lambda X: spmm_merge_distributed(sharded, X, mesh,
-                                                   impl=impl_r,
-                                                   num_chunks=chunks,
-                                                   op="T", gather=gx)
-    # the jitted closure keeps repeated flushes of one batch shape from
-    # retracing the shard_map body
-    jitted = jax.jit(eager)
-    jitted_t = jax.jit(eager_t)
+        mult = lambda sh, X, op: spmm_merge_distributed(
+            sh, X, mesh, impl=impl_r, num_chunks=chunks, op=op, gather=gx)
+    eager = lambda X: mult(sharded, X, "N")
+    eager_t = lambda X: mult(sharded, X, "T")
+    # jitted so repeated flushes of one batch shape do not retrace the
+    # shard_map body
+    jitted = _JitOver(lambda sh, X: mult(sh, X, "N"), sharded)
+    jitted_t = _JitOver(lambda sh, X: mult(sh, X, "T"), sharded)
     mesh_tag = f"{pd}x{pm}mesh" if pm > 1 else f"{pd}dev"
     cx_tag = "/cx=on" if compact else ""
     gx_tag = f"/gx={gather}" if compact and gather != "upfront" else ""
@@ -590,6 +609,7 @@ def _mesh_plan(sharded, sc, stats, mesh, *, schedule, chunks, pd, pm,
     else:
         label = (f"sellcs+merge@{mesh_tag}/chunks={chunks}"
                  f"{cx_tag}{gx_tag}{sym_tag}")
+    label += f"[{impl_r}]"
     # price the gather with the map the multiply EXECUTES: the chunked
     # merge gathers through the chunk plan's re-dealt map, not the base
     # partition's

@@ -71,17 +71,12 @@ def spmm_blocked(bs: BlockedSparse, x: Array) -> Array:
 
 
 def sellcs_slots_ref(data: Array, cols: Array, slice_of: Array, x2: Array,
-                     *, num_slices: int, chunk: int,
-                     col_map: Array | None = None) -> Array:
+                     *, num_slices: int, chunk: int) -> Array:
     """Raw-array slot accumulation [num_slices*chunk, k] — the jnp twin of
     ``repro.spmm.kernels.sellcs_slots`` and the XLA body of the distributed
-    schedules. No row permutation is applied. With ``col_map`` the stored
-    ``cols`` are compact ids mapped through it before indexing ``x2``
-    (the fused-gather mode; twin of ``_sellcs_fused_kernel``)."""
+    schedules. No row permutation is applied."""
     dtype = jnp.promote_types(data.dtype, x2.dtype)
     k = x2.shape[1]
-    if col_map is not None:
-        cols = col_map[cols]
     xs = x2[cols]                                       # [W, C, k]
     contrib = data[:, :, None] * xs                     # [W, C, k]
     slot = (slice_of[:, None] * chunk
@@ -91,14 +86,14 @@ def sellcs_slots_ref(data: Array, cols: Array, slice_of: Array, x2: Array,
 
 def sellcs_slots_chunk_ref(data: Array, cols: Array, slice_of: Array,
                            x2: Array, *, slice_start: int, num_slices: int,
-                           chunk: int, col_map: Array | None = None) -> Array:
+                           chunk: int) -> Array:
     """jnp twin of ``kernels.sellcs_slots_chunk``: slot accumulation over a
     chunk sub-stream whose ``slice_of`` is still global, rebased to the
     chunk-local slot space starting at ``slice_start``."""
     local = jnp.clip(slice_of.astype(jnp.int32) - slice_start, 0,
                      max(num_slices - 1, 0))
     return sellcs_slots_ref(data, cols, local, x2, num_slices=num_slices,
-                            chunk=chunk, col_map=col_map)
+                            chunk=chunk)
 
 
 def sellcs_slot_x(row_perm: Array, x2: Array, m: int) -> Array:

@@ -243,6 +243,26 @@ def test_maybe_block_passthrough_when_disabled():
     assert obs.maybe_block(x) is x
 
 
+class _FailedExecution:
+    """A device result whose execution failed: blocking on it raises, as
+    ``block_until_ready`` does for a real failed device computation."""
+
+    def block_until_ready(self):
+        raise RuntimeError("device execution failed")
+
+
+def test_maybe_block_propagates_device_errors():
+    """Regression: maybe_block used to swallow every exception from
+    block_until_ready, so a failed device execution still closed its span
+    as if it had run."""
+    obs.install(MetricRegistry())
+    try:
+        with pytest.raises(RuntimeError, match="device execution failed"):
+            obs.maybe_block(_FailedExecution())
+    finally:
+        obs.uninstall()
+
+
 # ---------------------------------------------------------------- ledger
 
 def test_ledger_residual_invariant():
@@ -308,6 +328,13 @@ def test_time_min_of_n_protocol_and_result():
     assert len(calls) == 6                  # warmup + reps, all executed
     assert r.reps == 4 and r.warmup == 2
     assert r.best_s >= 0 and r.last_result == 6
+
+
+def test_time_min_of_n_propagates_device_errors():
+    """Regression: the timer's block swallowed device errors and returned
+    a time for work that never ran."""
+    with pytest.raises(RuntimeError, match="device execution failed"):
+        time_min_of_n(_FailedExecution, reps=1, warmup=0)
 
 
 def test_time_min_of_n_rejects_bad_protocol():

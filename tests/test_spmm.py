@@ -129,17 +129,119 @@ def test_kernels_interpret_match_reference(k, k_tile):
         dense, rtol=RTOL, atol=ATOL)
 
 
+def test_sellcs_slots_gather_kernel_edge_streams():
+    """The DMA-gather kernel against its jnp twin on streams the σ-sorted
+    converter never makes but shards and padding do: slice ids that
+    revisit earlier slices (each finished slice is added into Y, never
+    overwritten), a width-row count that is no multiple of the grid
+    step, stored zeros mid-row, and (interpret mode only) a k-tile below
+    one lane, with X padded to a multiple of it."""
+    from repro.spmm.kernels import _pad_k, sellcs_slots
+    from repro.spmm.reference import sellcs_slots_ref
+    rng = np.random.default_rng(5)
+    W, C, S, n = 37, 8, 5, 20
+    data = rng.standard_normal((W, C)).astype(np.float32)
+    data[rng.random((W, C)) < 0.3] = 0.0
+    cols = rng.integers(0, n, (W, C)).astype(np.int32)
+    slice_of = rng.integers(0, S, W).astype(np.int32)
+    for k, kt in ((3, 3), (5, 2)):
+        x = jnp.asarray(rng.standard_normal((n, k)).astype(np.float32))
+        y = sellcs_slots(jnp.asarray(data), jnp.asarray(cols),
+                         jnp.asarray(slice_of), _pad_k(x, kt),
+                         num_slices=S, chunk=C, k_tile=kt, interpret=True)
+        ref = sellcs_slots_ref(jnp.asarray(data), jnp.asarray(cols),
+                               jnp.asarray(slice_of), x, num_slices=S,
+                               chunk=C)
+        assert y.shape[0] == ref.shape[0] and y.shape[1] % kt == 0
+        np.testing.assert_allclose(np.asarray(y[:, :k]), np.asarray(ref),
+                                   rtol=1e-5, atol=1e-5)
+
+
+def test_sellcs_slots_rejects_unaligned_widths():
+    """``choose_k_tile`` owns the width: ``sellcs_slots`` pads nothing, so
+    an X narrower than a whole number of k-tiles, or (for Mosaic) a k-tile
+    that is not a lane multiple, is refused before anything lowers."""
+    from repro.spmm.kernels import sellcs_slots
+    W, C, S, n = 8, 8, 2, 16
+    data = jnp.ones((W, C), jnp.float32)
+    cols = jnp.zeros((W, C), jnp.int32)
+    slice_of = jnp.zeros((W,), jnp.int32)
+    kw = dict(num_slices=S, chunk=C)
+    with pytest.raises(ValueError, match="multiple of k_tile"):
+        sellcs_slots(data, cols, slice_of, jnp.ones((n, 5), jnp.float32),
+                     k_tile=2, interpret=True, **kw)
+    with pytest.raises(ValueError, match="Mosaic"):
+        sellcs_slots(data, cols, slice_of, jnp.ones((n, 64), jnp.float32),
+                     k_tile=64, **kw)
+
+
+def test_auto_impl_resolves_per_format(monkeypatch):
+    """impl="auto" names the path that runs: on a TPU backend the Pallas
+    kernel only where one lowers (SELL-C-σ forward, general storage), the
+    XLA reference for every other format and op — and the reference
+    everywhere off the TPU. Operator plans record it in impl and label."""
+    import jax
+    from repro.spmm.kernels import resolve_impl
+    from repro.spmm.operator import SparseOperator
+    from repro.core import PlanSpec
+    coo = _matrices()["uniform"]
+    sc = M.coo_to_sellcs(coo, c=64, sigma=128)
+    sq = to_coo(*matrices.mesh2d(12))
+    sym = to_coo(np.concatenate([np.asarray(sq.rows), np.asarray(sq.cols)]),
+                 np.concatenate([np.asarray(sq.cols), np.asarray(sq.rows)]),
+                 np.concatenate([np.asarray(sq.data)] * 2), sq.shape)
+    ssym = M.coo_to_sellcs(sym, structure="symmetric")
+    others = (coo, coo_to_csr(coo), coo_to_tiled(coo, "csb", beta=128))
+    assert resolve_impl("auto", sc) == "ref"          # CPU backend
+    op = SparseOperator.from_coo(coo, PlanSpec(algorithm="parcrs"))
+    assert op.plan.impl == "ref" and op.plan.label == "parcrs[ref]"
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert resolve_impl("auto", sc) == "pallas"
+    assert resolve_impl("auto", sc, "T") == "ref"
+    assert resolve_impl("auto", ssym) == "ref"
+    assert all(resolve_impl("auto", m) == "ref" for m in others)
+    assert resolve_impl("pallas", coo_to_csr(coo)) == "pallas"
+    op = SparseOperator.from_coo(coo, PlanSpec(algorithm="bcohc"))
+    assert op.plan.impl == "ref" and op.plan.label == "bcohc[ref]"
+    op = SparseOperator.from_coo(coo, PlanSpec(algorithm="sellcs"))
+    assert op.plan.impl == "pallas" and op.plan.label == "sellcs[pallas]"
+
+
+def test_off_path_kernels_refuse_mosaic():
+    """Kernels that do not lower on Mosaic raise for interpret=False
+    instead of running something else; interpret mode still runs them."""
+    from repro.kernels import ops as kops
+    coo = _matrices()["uniform"]
+    X = jnp.ones((coo.shape[1], 4), jnp.float32)
+    ts = coo_to_tiled(coo, "csb", beta=128)
+    csr = coo_to_csr(coo)
+    for call in (lambda: M.tiled_spmm(ts, X),
+                 lambda: M.csr_spmm(csr, X),
+                 lambda: kops.bsr_spmv(ts, X[:, 0]),
+                 lambda: kops.merge_spmv(csr, X[:, 0]),
+                 lambda: M.spmm(csr, X, impl="pallas")):
+        with pytest.raises(NotImplementedError, match="Mosaic"):
+            call()
+
+
 def test_choose_k_tile_roofline():
-    # never exceeds k, never below 1
-    assert M.choose_k_tile((100, 100), 1) == 1
-    assert 1 <= M.choose_k_tile((100, 100), 7) <= 7
-    # VMEM bound: bigger matrices force smaller k-tiles
-    small = M.choose_k_tile((1000, 1000), 256, nnz=10 ** 5)
-    big = M.choose_k_tile((10 ** 6, 10 ** 6), 256, nnz=10 ** 7)
-    assert big <= small
-    # lane alignment once above one lane
-    kt = M.choose_k_tile((1000, 1000), 256, nnz=10 ** 7)
-    assert kt == 256 or kt % 128 == 0 or kt < 128
+    from repro.spmm.kernels import sellcs_vmem_bytes
+    from repro.core.convert import VMEM_BUDGET_BYTES
+    # one lane at least: HBM rows are DMA'd whole lanes, so a narrower
+    # tile would cost the same VMEM and the same DMAs
+    for k in (1, 7, 32, 128):
+        assert M.choose_k_tile(k) == 128
+    # the chosen tile fits the budget, is a lane multiple and never wider
+    # than k rounded up to a lane
+    for k in (1, 32, 200, 256, 1000, 1024, 4096):
+        kt = M.choose_k_tile(k)
+        assert kt % 128 == 0 and kt <= -(-k // 128) * 128
+        assert sellcs_vmem_bytes(kt) <= VMEM_BUDGET_BYTES
+    # X and Y stay in HBM, so only the budget (never n) sizes the tile
+    wide = M.choose_k_tile(1024, vmem_budget=4 * VMEM_BUDGET_BYTES)
+    assert wide > M.choose_k_tile(1024)
+    assert sellcs_vmem_bytes(wide) <= 4 * VMEM_BUDGET_BYTES
+    assert M.choose_k_tile(200, vmem_budget=4 * VMEM_BUDGET_BYTES) == 256
 
 
 def test_arithmetic_intensity_monotone_in_k():
@@ -292,7 +394,7 @@ def test_select_distributed_records_num_chunks():
     algo, sched, nc, mesh, cx, st, gx = choice   # unpacks like a tuple
     assert (algo, sched, nc, mesh, cx, st, gx) == tuple(choice)
     assert st == "general"                    # nothing symmetric here
-    assert gx in ("upfront", "overlap", "fused")
+    assert gx in ("upfront", "overlap")
     assert mesh[0] * mesh[1] == 8
     assert select_distributed(uni, k=8, num_devices=8).num_chunks == 1
 

@@ -1,16 +1,15 @@
 """Hiding the compact-X gather under the chunked slice stream (ISSUE 10):
 ``gather="overlap"`` rebuilds the gathered slab per span inside the mesh
-body so XLA can run span i+1's gather under span i's kernel/psum, and
-``gather="fused"`` folds the indirection into the Pallas kernel's scalar
-prefetch. Both must be BITWISE identical to the up-front gather — they
-move the same bytes at a different time, in the same fp summation order —
+body so XLA can run span i+1's gather under span i's kernel/psum. It must
+be BITWISE identical to the up-front gather — it moves the same bytes at
+a different time, in the same fp summation order —
 across schedules x chunks {1,2,4} x meshes (8,1)/(4,2) x op N/T x
 uniform/mawi, under the jnp reference body and the Pallas kernel body in
 interpret mode, plus the degenerates (nnz==0 shard, a shard touching all
 n columns, n_touched < LANE).
 
 Also locked down here: the exposed-gather roofline term's ordering
-(fused <= overlap <= upfront, zero off the compact path), the selector's
+(overlap <= upfront, zero off the compact path), the selector's
 gather axis (PlanSpec pin, validation), the baked per-span touched-column
 split's invariants (LANE-padded col_map, the row-0 padding pair), and the
 ``_symmetric_combine`` mixed-dtype regression (a wider stored diagonal
@@ -42,8 +41,8 @@ def run_sub(code: str, devices: int = 8) -> str:
 
 
 def test_gather_modes_bitwise_equal_and_oracle():
-    """ISSUE 10 acceptance: overlapped and fused gathers answer BITWISE
-    identically to the up-front gather (and all three match the
+    """The overlapped gather answers BITWISE identically to the up-front
+    gather (and both match the
     ``SellCS.to_coo`` oracle) across meshes (8,1)/(4,2), both schedules,
     num_chunks in {1, 2, 4}, op N/T, uniform + mawi."""
     print(run_sub("""
@@ -72,46 +71,40 @@ for name, gen in [("uniform", matrices.uniform(500, 430, 4000, 0)),
                                                    gather="upfront"))
             np.testing.assert_allclose(y_up, yo, rtol=1e-5, atol=1e-4,
                                        err_msg=f"{name} row {pd}x{pm}")
-            for g in ("overlap", "fused"):
-                np.testing.assert_array_equal(
-                    np.asarray(spmm_row_distributed(row, X, mesh,
-                                                    gather=g)),
-                    y_up, err_msg=f"{name} row {pd}x{pm} k={k} gx={g}")
+            np.testing.assert_array_equal(
+                np.asarray(spmm_row_distributed(row, X, mesh,
+                                                gather="overlap")),
+                y_up, err_msg=f"{name} row {pd}x{pm} k={k}")
             for c, mrg in mrgs.items():
                 y_up = np.asarray(spmm_merge_distributed(
                     mrg, X, mesh, num_chunks=c, gather="upfront"))
                 np.testing.assert_allclose(
                     y_up, yo, rtol=1e-5, atol=1e-4,
                     err_msg=f"{name} merge/c{c} {pd}x{pm}")
-                for g in ("overlap", "fused"):
-                    np.testing.assert_array_equal(
-                        np.asarray(spmm_merge_distributed(
-                            mrg, X, mesh, num_chunks=c, gather=g)),
-                        y_up,
-                        err_msg=f"{name} merge/c{c} {pd}x{pm} k={k} "
-                                f"gx={g}")
+                np.testing.assert_array_equal(
+                    np.asarray(spmm_merge_distributed(
+                        mrg, X, mesh, num_chunks=c, gather="overlap")),
+                    y_up, err_msg=f"{name} merge/c{c} {pd}x{pm} k={k}")
             # op=T has no compact-X gather (X is read dense in slot
             # space) — gather= is accepted and ignored, bitwise
             XT = jnp.asarray(np.random.default_rng(k + 7).standard_normal(
                 (coo.shape[0], k)).astype(np.float32))
             yt = np.asarray(spmm_merge_distributed(mrgs[2], XT, mesh,
                                                    num_chunks=2, op="T"))
-            for g in ("overlap", "fused"):
-                np.testing.assert_array_equal(
-                    np.asarray(spmm_merge_distributed(
-                        mrgs[2], XT, mesh, num_chunks=2, op="T",
-                        gather=g)),
-                    yt, err_msg=f"{name} op=T {pd}x{pm} k={k} gx={g}")
+            np.testing.assert_array_equal(
+                np.asarray(spmm_merge_distributed(
+                    mrgs[2], XT, mesh, num_chunks=2, op="T",
+                    gather="overlap")),
+                yt, err_msg=f"{name} op=T {pd}x{pm} k={k}")
     print(name, "gather modes OK")
 """))
 
 
 def test_gather_modes_pallas_interpret():
-    """The fused mode's real body: the Pallas kernel takes the LANE-padded
-    global col_map as a second scalar-prefetch operand and does the
-    two-level take itself (interpret mode off-TPU). Fused and overlapped
-    results must stay bitwise equal to up-front under the kernel body,
-    and all match the oracle."""
+    """The gather modes under the Pallas kernel body (interpret mode
+    off-TPU): the overlapped result stays bitwise equal to up-front, the
+    replicated-X stream (no compaction) answers the same, and all match
+    the oracle."""
     print(run_sub("""
 import numpy as np, jax.numpy as jnp
 from repro.core import to_coo
@@ -125,6 +118,7 @@ sc = coo_to_sellcs(coo, c=16, sigma=64)
 for pd, pm in [(8, 1), (4, 2)]:
     mesh = make_spmm_mesh((pd, pm))
     row = partition_sellcs_rows(sc, pd, compact_x=True)
+    plain = partition_sellcs_rows(sc, pd)
     mrg = partition_sellcs_nnz(sc, pd, num_chunks=4, compact_x=True)
     for k in (1, 8):
         X = jnp.asarray(np.random.default_rng(k).standard_normal(
@@ -137,18 +131,21 @@ for pd, pm in [(8, 1), (4, 2)]:
         np.testing.assert_array_equal(
             np.asarray(spmm_row_distributed(
                 row, X, mesh, impl="pallas_interpret", k_tile=4,
-                gather="fused")),
-            y_up, err_msg=f"row fused {pd}x{pm} k={k}")
+                gather="overlap")),
+            y_up, err_msg=f"row overlap {pd}x{pm} k={k}")
+        np.testing.assert_allclose(
+            np.asarray(spmm_row_distributed(
+                plain, X, mesh, impl="pallas_interpret", k_tile=4)),
+            yo, rtol=1e-5, atol=1e-4, err_msg=f"row plain {pd}x{pm} k={k}")
         m_up = np.asarray(spmm_merge_distributed(
             mrg, X, mesh, impl="pallas_interpret", k_tile=4,
             num_chunks=4, gather="upfront"))
         np.testing.assert_allclose(m_up, yo, rtol=1e-5, atol=1e-4)
-        for g in ("overlap", "fused"):
-            np.testing.assert_array_equal(
-                np.asarray(spmm_merge_distributed(
-                    mrg, X, mesh, impl="pallas_interpret", k_tile=4,
-                    num_chunks=4, gather=g)),
-                m_up, err_msg=f"merge {g} {pd}x{pm} k={k}")
+        np.testing.assert_array_equal(
+            np.asarray(spmm_merge_distributed(
+                mrg, X, mesh, impl="pallas_interpret", k_tile=4,
+                num_chunks=4, gather="overlap")),
+            m_up, err_msg=f"merge overlap {pd}x{pm} k={k}")
     print(pd, pm, "gather interpret OK")
 """))
 
@@ -172,7 +169,7 @@ z = np.zeros(0, np.int32)
 empty = to_coo(z, z, np.zeros(0, np.float32), (6, 4))
 se = coo_to_sellcs(empty, c=2, sigma=4)
 X4 = jnp.ones((4, 3), jnp.float32)
-for g in ("upfront", "overlap", "fused"):
+for g in ("upfront", "overlap"):
     assert np.abs(np.asarray(spmm_row_distributed(
         partition_sellcs_rows(se, 8, compact_x=True), X4, mesh,
         gather=g))).max() == 0, g
@@ -191,10 +188,9 @@ yo = np.asarray(spmm_coo(sc.to_coo(), X))
 y_up = np.asarray(spmm_merge_distributed(mrg, X, mesh, num_chunks=4,
                                          gather="upfront"))
 np.testing.assert_allclose(y_up, yo, rtol=1e-5, atol=1e-4)
-for g in ("overlap", "fused"):
-    np.testing.assert_array_equal(
-        np.asarray(spmm_merge_distributed(mrg, X, mesh, num_chunks=4,
-                                          gather=g)), y_up, g)
+np.testing.assert_array_equal(
+    np.asarray(spmm_merge_distributed(mrg, X, mesh, num_chunks=4,
+                                      gather="overlap")), y_up)
 
 # 3. n_touched < LANE everywhere (4 distinct columns): the slab is pure
 # pad beyond row 4 and every mode must read only the real rows
@@ -206,7 +202,7 @@ mrg = partition_sellcs_nnz(sc, 8, num_chunks=4, compact_x=True)
 X = jnp.asarray(np.random.default_rng(1).standard_normal(
     (4, 8)).astype(np.float32))
 yo = np.asarray(spmm_coo(sc.to_coo(), X))
-for g in ("upfront", "overlap", "fused"):
+for g in ("upfront", "overlap"):
     np.testing.assert_allclose(
         np.asarray(spmm_row_distributed(row, X, mesh, gather=g)),
         yo, rtol=1e-5, atol=1e-4, err_msg=g)
@@ -235,7 +231,7 @@ def _mawi_sellcs(c=8, sigma=32):
 
 
 def test_gather_knob_validation():
-    """overlap/fused need a compact partition (a replicated-X stream has
+    """overlap needs a compact partition (a replicated-X stream has
     no X gather to hide); an unknown mode is a ValueError naming the
     choices."""
     import jax
@@ -249,20 +245,19 @@ def test_gather_knob_validation():
     X = np.ones((180, 2), np.float32)
     plain = partition_sellcs_rows(sc, 1)
     comp = partition_sellcs_rows(sc, 1, compact_x=True)
-    for g in ("overlap", "fused"):
-        with pytest.raises(ValueError, match="compact"):
-            spmm_row_distributed(plain, X, mesh, gather=g)
-        with pytest.raises(ValueError, match="compact"):
-            spmm_merge_distributed(partition_sellcs_nnz(sc, 1), X, mesh,
-                                   gather=g)
-    with pytest.raises(ValueError, match="gather"):
-        spmm_row_distributed(comp, X, mesh, gather="bogus")
+    with pytest.raises(ValueError, match="compact"):
+        spmm_row_distributed(plain, X, mesh, gather="overlap")
+    with pytest.raises(ValueError, match="compact"):
+        spmm_merge_distributed(partition_sellcs_nnz(sc, 1), X, mesh,
+                               gather="overlap")
+    for g in ("bogus", "fused"):
+        with pytest.raises(ValueError, match="gather"):
+            spmm_row_distributed(comp, X, mesh, gather=g)
     # on one device every mode is the same single gather — bitwise
     y_up = np.asarray(spmm_row_distributed(comp, X, mesh))
-    for g in ("overlap", "fused"):
-        np.testing.assert_array_equal(
-            np.asarray(spmm_row_distributed(comp, X, mesh, gather=g)),
-            y_up)
+    np.testing.assert_array_equal(
+        np.asarray(spmm_row_distributed(comp, X, mesh, gather="overlap")),
+        y_up)
 
 
 def test_span_maps_lane_padded_and_row0_invariant():
@@ -302,7 +297,7 @@ def test_span_maps_lane_padded_and_row0_invariant():
 
 
 def test_exposed_gather_roofline_term():
-    """fused <= overlap <= upfront always; overlap strictly wins only
+    """overlap <= upfront always; overlap strictly wins only
     where there are spans to hide behind (merge, num_chunks > 1); the
     term is zero off the compact path and for op=T."""
     from repro.roofline import spmm_distributed_gather_s
@@ -312,9 +307,10 @@ def test_exposed_gather_roofline_term():
                                    num_chunks=4, gather="upfront", **kw)
     ov = spmm_distributed_gather_s(5000, 4000, 32, 8, "merge",
                                    num_chunks=4, gather="overlap", **kw)
-    fu = spmm_distributed_gather_s(5000, 4000, 32, 8, "merge",
-                                   num_chunks=4, gather="fused", **kw)
-    assert fu == 0.0 and fu <= ov <= up and ov < up
+    assert 0.0 < ov < up
+    with pytest.raises(ValueError, match="gather"):
+        spmm_distributed_gather_s(5000, 4000, 32, 8, "merge",
+                                  num_chunks=4, gather="fused", **kw)
     # no spans to hide behind: overlap degenerates to up-front
     for sched, nc in (("row", 1), ("merge", 1)):
         u = spmm_distributed_gather_s(5000, 4000, 32, 8, sched,
@@ -341,7 +337,7 @@ def test_selector_gather_axis_and_spec_pin():
     compact_x (a replicated-X plan has no gather to schedule)."""
     from repro.core import (GATHER_CANDIDATES, MatrixStats, PlanSpec,
                             select_distributed)
-    assert GATHER_CANDIDATES == ("upfront", "overlap", "fused")
+    assert GATHER_CANDIDATES == ("upfront", "overlap")
     stats = MatrixStats(m=20000, n=20000, nnz=300000, max_row_nnz=64,
                         row_var=0.4, symmetric=False)
     ch = select_distributed(stats, k=64, num_devices=8)
@@ -357,7 +353,9 @@ def test_selector_gather_axis_and_spec_pin():
         PlanSpec(num_devices=8, gather="bogus").canonical()
     with pytest.raises(ValueError, match="compact"):
         PlanSpec(num_devices=8, compact_x=False,
-                 gather="fused").canonical()
+                 gather="overlap").canonical()
+    with pytest.raises(ValueError, match="gather"):
+        PlanSpec(num_devices=8, compact_x=True, gather="fused").canonical()
 
 
 def test_symmetric_combine_mixed_dtype_regression():
