@@ -471,9 +471,10 @@ def _realize_single(coo, stats, spec, *, impl, k_hint, num_spmvs, t0,
         raise ValueError(
             "structure='symmetric' (one-triangle storage) is executable "
             f"only on the SELL-C-σ stream, not {algo!r}")
-    if algo == "sellcs" and structure != "general":
+    if algo == "sellcs":
         from repro.spmm import coo_to_sellcs
-        mat = coo_to_sellcs(coo, structure=structure)
+        # σ = m: the DMA-gather kernel draws no locality from σ; least padding
+        mat = coo_to_sellcs(coo, sigma=coo.shape[0], structure=structure)
     else:
         mat = convert(coo, algo)
     mat_bytes = _matrix_bytes_est(algo, stats)

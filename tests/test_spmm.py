@@ -6,6 +6,7 @@ calls to fp32 tolerance — including the mawi-style skewed generator.
 """
 import numpy as np
 import pytest
+import jax
 import jax.numpy as jnp
 
 from repro.core import (MachineSpec, convert, coo_to_csr, matrix_stats,
@@ -102,6 +103,71 @@ def test_sellcs_convert_registration():
     np.testing.assert_allclose(np.asarray(spmv(sc, x)),
                                np.asarray(spmv_coo(coo, x)),
                                rtol=RTOL, atol=ATOL)
+
+
+def _skewed(name):
+    """Skewed square matrices whose rows span several default σ-windows.
+    ``rmat16`` relabels the vertices by a random permutation, as Graph500's
+    kernel 1 does, which spreads the hubs over every window."""
+    if name == "rmat16":
+        rows, cols, vals, shape = matrices.rmat(16, 16, seed=0)
+        perm = np.random.default_rng(1).permutation(shape[0])
+        return to_coo(perm[rows], perm[cols], vals, shape)
+    rows, cols, vals, shape = matrices.powerlaw(6000, 6000, 48000, 1.8,
+                                                seed=4)
+    if name == "powerlaw_sym":
+        rows, cols, vals = (np.concatenate([rows, cols]),
+                            np.concatenate([cols, rows]),
+                            np.concatenate([vals, vals]))
+    return to_coo(rows, cols, vals, shape)
+
+
+# min_ratio: σ-window width-rows over the global sort's, a little under
+# the CPU's reading (1.66, 1.29 and 4.67)
+@pytest.mark.parametrize("name,structure,min_ratio", [
+    ("powerlaw", "general", 1.6),
+    ("powerlaw_sym", "symmetric", 1.25),
+    ("rmat16", "general", 4.5),
+])
+def test_single_device_plan_takes_one_global_sort(name, structure,
+                                                  min_ratio):
+    """The one-device operator's stream is ``coo_to_sellcs`` at σ = m,
+    array for array, and on a skewed matrix over many σ-windows it walks
+    far fewer width-rows than the converter's default σ-window stream."""
+    from repro.core import PlanSpec
+    from repro.spmm.sellcs import DEFAULT_SIGMA_SLICES
+    coo = _skewed(name)
+    m = coo.shape[0]
+    op = M.SparseOperator.from_coo(
+        coo, PlanSpec(num_devices=1, algorithm="sellcs",
+                      structure=structure), impl="ref")
+    got = op.plan.matrix
+    want = M.coo_to_sellcs(coo, sigma=m, structure=structure)
+    got_arrays, got_def = jax.tree_util.tree_flatten(got)
+    want_arrays, want_def = jax.tree_util.tree_flatten(want)
+    assert got_def == want_def          # shape, chunk, sigma, nnz, structure
+    for a, b in zip(got_arrays, want_arrays):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    window = M.coo_to_sellcs(coo, structure=structure)
+    assert window.sigma == DEFAULT_SIGMA_SLICES * window.chunk < m
+    assert window.data.shape[0] >= min_ratio * got.data.shape[0]
+
+
+@pytest.mark.parametrize("k,k_tile", [(1, None), (8, 4)])
+def test_global_sort_answers_equal_window_sort_bitwise(k, k_tile):
+    """Every row keeps its entries in column order under any σ, and the
+    kernel sums a row's lanes in that order from zero, so the global sort
+    and a σ-window stream give the same answers to the bit."""
+    coo = _matrices()["mawi_like"]
+    m, n = coo.shape
+    window = M.coo_to_sellcs(coo, c=8, sigma=16)
+    glob = M.coo_to_sellcs(coo, c=8, sigma=m)
+    assert glob.data.shape[0] < window.data.shape[0]
+    X = jnp.asarray(np.random.default_rng(11).standard_normal(
+        (n, k)).astype(np.float32))
+    ya = M.sellcs_spmm(window, X, k_tile=k_tile, interpret=True)
+    yb = M.sellcs_spmm(glob, X, k_tile=k_tile, interpret=True)
+    assert np.array_equal(np.asarray(ya), np.asarray(yb))
 
 
 # --------------------------------------------------------------------------

@@ -157,6 +157,7 @@ import numpy as np, jax.numpy as jnp
 from repro.core import PlanSpec, to_coo
 from repro.data import matrices
 from repro.spmm import SparseOperator, spmm_coo, spmm_coo_t
+from repro.spmm.sellcs import DEFAULT_SIGMA_SLICES
 coo = to_coo(*matrices.uniform(3000, 3000, 60000, 0))
 X = jnp.asarray(np.random.default_rng(0).standard_normal(
     (3000, 8)).astype(np.float32))
@@ -164,6 +165,9 @@ for sched, nc in (("row", 1), ("merge", 2)):
     op = SparseOperator.from_coo(coo, PlanSpec(
         num_devices=4, mesh_shape=(4, 1), schedule=sched, num_chunks=nc,
         compact_x=False, algorithm="sellcs"), impl="ref")
+    # the mesh keeps the σ-window: its bands deal neighbouring rows
+    sc = op.plan.local_matrix
+    assert sc.sigma == DEFAULT_SIGMA_SLICES * sc.chunk < 3000, sched
     with warnings.catch_warnings(record=True) as caught:
         warnings.simplefilter("always")
         y, yt = op.matmul(X), op.rmatmul(X)
