@@ -173,6 +173,16 @@ class ShardedSellCS(NamedTuple):
         return int(total)
 
 
+def _check_unsplit(sc: SellCS) -> None:
+    """The mesh schedules assume each row owns one slot: the row bands
+    scatter their slots with no collective. A stream with split rows
+    (``coo_to_sellcs(split_long_rows=True)``) is for one device only."""
+    if sc.split_lanes:
+        raise ValueError("a SELL-C-σ stream with split rows cannot be "
+                         "partitioned over a mesh; build it without "
+                         "split_long_rows")
+
+
 def _compact_columns(Cc: np.ndarray, counts: np.ndarray):
     """Host-side, convert time: per-shard touched-column maps over the
     device-dealt ``cols`` blocks.
@@ -285,6 +295,7 @@ def partition_sellcs_rows(sc: SellCS, num_devices: int, *,
     reading the full replicated slab (see the module docstring).
     """
     _check_devices(num_devices)
+    _check_unsplit(sc)
     C = sc.chunk
     S = sc.num_slices
     D, Cc, So, bounds, Sp, counts = _deal_slice_bands(
@@ -325,6 +336,7 @@ def partition_sellcs_nnz(sc: SellCS, num_devices: int, *,
     the re-dealt ownership.
     """
     _check_devices(num_devices)
+    _check_unsplit(sc)
     if num_chunks < 1:
         raise ValueError(f"num_chunks must be >= 1, got {num_chunks}")
     C = sc.chunk

@@ -611,11 +611,15 @@ def sellcs_spmm(sc: SellCS, x: jax.Array, *, k_tile: Optional[int] = None,
         reg.counter("sellcs/width_rows").inc(W * nk)
         reg.counter("sellcs/slots").inc(W * sc.chunk * nk)
         reg.counter("sellcs/lanes_live").inc(sc.nnz * nk)
+        reg.counter("sellcs/split_slots").inc(sc.split_slots * nk)
+        reg.counter("sellcs/split_lanes").inc(sc.split_lanes * nk)
     with span("spmm/kernel"):
         # the k-tile padding columns stop here: the σ-unpermute moves k only
         y_slots = _sellcs_spmm_slots(sc, x_pad, k_tile=kt,
                                      interpret=interpret)[:, :k]  # (S*C, k)
     with span("spmm/fixup"):
+        # row_perm is not injective: the virtual rows of a split row share
+        # its id, and the scatter-add sums them into it
         y = jnp.zeros((m + 1, k), jnp.float32).at[sc.row_perm].add(y_slots)
         y = y[:m]
     if sym:

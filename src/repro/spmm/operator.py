@@ -473,8 +473,10 @@ def _realize_single(coo, stats, spec, *, impl, k_hint, num_spmvs, t0,
             f"only on the SELL-C-σ stream, not {algo!r}")
     if algo == "sellcs":
         from repro.spmm import coo_to_sellcs
-        # σ = m: the DMA-gather kernel draws no locality from σ; least padding
-        mat = coo_to_sellcs(coo, sigma=coo.shape[0], structure=structure)
+        # σ = m: the DMA-gather kernel draws no locality from σ; least
+        # padding. Split rows: a hub row would pad its slice C-fold
+        mat = coo_to_sellcs(coo, sigma=coo.shape[0], structure=structure,
+                            split_long_rows=True)
     else:
         mat = convert(coo, algo)
     mat_bytes = _matrix_bytes_est(algo, stats)

@@ -13,6 +13,15 @@ a slice is one (C,)-lane vector: the SpMM kernel broadcasts it against a
 scatter. Row sorting is a *permutation*, recorded in ``row_perm`` and undone
 by a single scatter at the end of the multiply.
 
+Row splitting (``split_long_rows=True``, the one-device plan): a slice is
+as wide as its longest row, so one hub row of L entries costs C·L slots.
+Every row longer than the C-th longest row's length L* becomes ⌈L/L*⌉
+*virtual rows* of near-equal length, each a consecutive run of the row's
+columns, and the virtual rows are sorted with the others. Each keeps its
+row's id in ``row_perm``, which then maps several slots to one row; the
+unpermute scatter-add sums them. A matrix whose rows are all ≤ L* gets
+the unsplit stream, bit for bit.
+
 Layout (width-major, slice-concatenated):
 
   ``data[w, l]`` / ``cols[w, l]`` — the ``j``-th nonzero of the row in lane
@@ -54,7 +63,8 @@ class SellCS:
     slice_ptr: Array       # int32[S+1] — width offset of each slice
     slice_of: Array        # int32[W] — owning slice of each width-row
     row_perm: Array        # int32[S*C] — permuted slot -> original row
-                           #   (padding slots point at m, dropped on scatter)
+                           #   (padding slots point at m, dropped on scatter;
+                           #   the virtual rows of a split row share its id)
     row_len: Array         # int32[S*C] — true nnz of each permuted slot
     diag: Optional[Array]  # f32[m] dense diagonal (symmetric mode), else None
     shape: Tuple[int, int] = static_field()
@@ -63,6 +73,9 @@ class SellCS:
     nnz: int = static_field()            # stored nonzeros before padding
                                          #   (one triangle in symmetric mode)
     structure: str = static_field(default="general")   # "general"|"symmetric"
+    split_slots: int = static_field(default=0)   # W·C of the slices that
+                                                 #   hold a virtual row
+    split_lanes: int = static_field(default=0)   # entries of virtual rows
 
     @property
     def num_slices(self) -> int:
@@ -163,9 +176,76 @@ def _symmetric_lower(rows, cols, vals, shape):
     return rows[keep], cols[keep], vals[keep], diag
 
 
+def _split_cap(row_len: np.ndarray, c: int) -> int:
+    """L*, the length of the ``c``-th longest row: no row is longer once
+    split, and the first slices come out full. 0 where nothing would be
+    split: no row is longer, fewer than ``c`` rows are non-empty, or the
+    matrix has fewer than ``c`` rows."""
+    m = row_len.size
+    if m < c:
+        return 0
+    cap = int(np.partition(row_len, m - c)[m - c])
+    return cap if cap and int(row_len.max()) > cap else 0
+
+
+def _split_long_rows(rows, cols, row_len, cap):
+    """Replace every row longer than ``cap`` with ⌈L/cap⌉ virtual rows of
+    near-equal length, each a run of the row's entries in column order.
+    A row's first piece keeps the row's id; the other pieces are numbered
+    from m up. Returns each entry's virtual row, each virtual row's owner
+    row and length, and the ids of the long rows."""
+    m = row_len.size
+    long = np.flatnonzero(row_len > cap)
+    L = row_len[long]
+    q = -(-L // cap)                       # pieces per long row
+    base, rem = L // q, L % q              # the first rem pieces hold base+1
+    extra = q - 1
+    first = m + np.cumsum(extra) - extra   # id of each long row's piece 1
+    is_long = np.zeros(m, bool)
+    is_long[long] = True
+    sel = np.flatnonzero(is_long[rows])
+    sel = sel[np.lexsort((cols[sel], rows[sel]))]   # grouped as ``long``
+    which = np.repeat(np.arange(long.size), L)
+    j = np.arange(sel.size) - np.repeat(np.cumsum(L) - L, L)
+    b, r = base[which], rem[which]
+    big = r * (b + 1)
+    piece = np.where(j < big, j // (b + 1), r + (j - big) // b)
+    vrows = rows.copy()
+    vrows[sel] = np.where(piece == 0, long[which], first[which] + piece - 1)
+    k = np.repeat(np.arange(long.size), extra)
+    pk = np.arange(k.size) - (first - m)[k] + 1     # piece number, 1..q-1
+    owner = np.concatenate([np.arange(m, dtype=np.int64), long[k]])
+    vlen = np.concatenate([row_len, base[k] + (pk < rem[k])])
+    vlen[long] = base + (rem > 0)
+    return vrows, owner, vlen, long
+
+
+def _layout(vlen, owner, m: int, sigma: int, c: int):
+    """The σ-window sort of the (virtual) rows and the stream's shape.
+    Rows are ordered by (window, -length, id) — stable, so equal-length
+    rows keep their relative order (reproducible); a virtual row sorts in
+    its owner row's window. ``owner`` None: every row is its own."""
+    V = vlen.size
+    vid = np.arange(V, dtype=np.int64)
+    own = vid if owner is None else owner
+    order = np.lexsort((vid, -vlen, own // sigma))     # pos -> virtual row
+    S = max(-(-V // c), 1)
+    slots = S * c
+    row_perm = np.full(slots, m, np.int64)
+    row_perm[:V] = own[order]
+    row_len = np.zeros(slots, np.int64)
+    row_len[:V] = vlen[order]
+    widths = row_len.reshape(S, c).max(axis=1)      # per-slice width
+    slice_ptr = np.zeros(S + 1, np.int64)
+    np.cumsum(widths, out=slice_ptr[1:])
+    slice_of = np.repeat(np.arange(S, dtype=np.int64), widths)
+    return order, row_perm, row_len, slice_ptr, slice_of
+
+
 def coo_to_sellcs(coo: COO, *, c: int = DEFAULT_C,
                   sigma: Optional[int] = None,
-                  structure: str = "general") -> SellCS:
+                  structure: str = "general",
+                  split_long_rows: bool = False) -> SellCS:
     """Convert COO -> SELL-C-σ (host-side, like every conversion here).
 
     ``sigma`` is the row-sorting window in rows; it is rounded up to a
@@ -176,6 +256,11 @@ def coo_to_sellcs(coo: COO, *, c: int = DEFAULT_C,
     ``structure="symmetric"`` stores one triangle (``row >= col``) plus a
     dense diagonal; the input must satisfy ``A == A^T`` exactly (pattern
     and values) or a ``ValueError`` is raised.
+
+    ``split_long_rows=True`` splits every stored row longer than the
+    ``c``-th longest one into virtual rows (module docstring). The mesh
+    partitioners refuse such a stream: their row bands assume each row
+    lives in one slot.
     """
     m, n = coo.shape
     if c < 1:
@@ -199,31 +284,33 @@ def coo_to_sellcs(coo: COO, *, c: int = DEFAULT_C,
                                                       coo.shape)
         row_len_orig = (np.bincount(rows, minlength=m).astype(np.int64)
                         if m else np.zeros(0, np.int64))
-        # σ-window sort: rows ordered by (window, -length, row) — stable,
-        # so equal-length rows keep their relative order (reproducible).
-        ridx = np.arange(m, dtype=np.int64)
-        window = ridx // sigma
-        order = np.lexsort((ridx, -row_len_orig, window))  # pos -> row
+        cap = _split_cap(row_len_orig, c) if split_long_rows else 0
+        if not cap:
+            order, row_perm, row_len, slice_ptr, slice_of = _layout(
+                row_len_orig, None, m, sigma, c)
 
-        S = max(-(-m // c), 1)
-        slots = S * c
-        row_perm = np.full(slots, m, np.int64)
-        row_perm[:m] = order
-        row_len = np.zeros(slots, np.int64)
-        row_len[:m] = row_len_orig[order]
-
-        widths = row_len.reshape(S, c).max(axis=1)      # per-slice width
-        slice_ptr = np.zeros(S + 1, np.int64)
-        np.cumsum(widths, out=slice_ptr[1:])
-        W = int(slice_ptr[-1])
-        slice_of = np.repeat(np.arange(S, dtype=np.int64), widths)
+    split_slots = split_lanes = 0
+    if cap:
+        with span("convert/sellcs_split"):
+            rows, owner, vlen, long = _split_long_rows(rows, cols,
+                                                       row_len_orig, cap)
+            order, row_perm, row_len, slice_ptr, slice_of = _layout(
+                vlen, owner, m, sigma, c)
+            is_piece = np.zeros(vlen.size, bool)
+            is_piece[long] = True
+            is_piece[m:] = True
+            held = np.unique(np.flatnonzero(is_piece[order]) // c)
+            split_slots = int(np.sum(np.diff(slice_ptr)[held])) * c
+            split_lanes = int(row_len_orig[long].sum())
+    W = int(slice_ptr[-1])
+    slots = row_len.size
 
     with span("convert/sellcs_fill"):
         data = np.zeros((W, c), np.float32 if vals.size == 0 else vals.dtype)
         col_arr = np.zeros((W, c), np.int64)
         if rows.size:
-            inv = np.empty(m, np.int64)
-            inv[order] = np.arange(m)
+            inv = np.empty(order.size, np.int64)
+            inv[order] = np.arange(order.size)
             p = inv[rows]                               # permuted position
             sort2 = np.lexsort((cols, p))
             p, cc, vv = p[sort2], cols[sort2], vals[sort2]
@@ -247,4 +334,5 @@ def coo_to_sellcs(coo: COO, *, c: int = DEFAULT_C,
             row_len=jnp.asarray(row_len.astype(np.int32)),
             diag=None if diag is None else jnp.asarray(diag),
             shape=coo.shape, chunk=int(c), sigma=int(sigma),
-            nnz=int(rows.size), structure=structure))
+            nnz=int(rows.size), structure=structure,
+            split_slots=split_slots, split_lanes=split_lanes))

@@ -18,9 +18,10 @@ from repro.spmm.kernels import sellcs_spmm
 
 REGISTRATION = ("convert/canonicalize", "convert/put", "convert/pull",
                 "convert/stats", "convert/sellcs_order",
-                "convert/sellcs_fill")
+                "convert/sellcs_split", "convert/sellcs_fill")
 FLUSH = ("spmm/pad_x", "spmm/kernel", "spmm/fixup")
-COUNTERS = ("sellcs/width_rows", "sellcs/slots", "sellcs/lanes_live")
+COUNTERS = ("sellcs/width_rows", "sellcs/slots", "sellcs/lanes_live",
+            "sellcs/split_slots", "sellcs/split_lanes")
 
 
 @pytest.fixture(autouse=True)
@@ -34,6 +35,17 @@ def _no_registry():
 def small():
     r, c, v, shape = rmat(8, 4, seed=0)
     return coo_to_sellcs(to_coo(r, c, v, shape))
+
+
+@pytest.fixture(scope="module")
+def small_split():
+    """The same matrix as the one-device plan streams it: its hubs split
+    into virtual rows."""
+    r, c, v, shape = rmat(8, 4, seed=0)
+    sc = coo_to_sellcs(to_coo(r, c, v, shape), sigma=shape[0],
+                       split_long_rows=True)
+    assert sc.split_lanes > 0
+    return sc
 
 
 def _register(r, c, v, shape):
@@ -57,11 +69,11 @@ def test_registration_spans_cover_to_coo_and_from_coo():
         wall, reg = _register(r, c, v, shape)
         hists = {name: reg.histogram(name) for name in REGISTRATION}
         # put: the COO's upload and the stream's; pull: matrix_stats's and
-        # coo_to_sellcs's reads of the COO
+        # coo_to_sellcs's reads of the COO; the graph's hubs are split
         assert {n: h.count for n, h in hists.items()} == {
             "convert/canonicalize": 1, "convert/put": 2, "convert/pull": 2,
             "convert/stats": 1, "convert/sellcs_order": 1,
-            "convert/sellcs_fill": 1}
+            "convert/sellcs_split": 1, "convert/sellcs_fill": 1}
         shares.append(sum(h.total for h in hists.values()) / wall)
     # the spans are siblings, so their totals cannot pass the wall time;
     # the best of three leaves out a scheduler's pause between two spans
@@ -69,10 +81,11 @@ def test_registration_spans_cover_to_coo_and_from_coo():
     assert max(shares) >= 0.9, shares
 
 
+@pytest.mark.parametrize("split", [False, True], ids=["whole", "split"])
 @pytest.mark.parametrize("k,k_tile,nk", [(1, None, 1), (5, 4, 2)],
                          ids=["k1", "two_k_tiles"])
-def test_flush_counters_follow_the_stream(small, k, k_tile, nk):
-    sc = small
+def test_flush_counters_follow_the_stream(request, k, k_tile, nk, split):
+    sc = request.getfixturevalue("small_split" if split else "small")
     x = jnp.asarray(np.random.default_rng(k).standard_normal(
         (sc.shape[1], k)).astype(np.float32))
     reg = obs.install(MetricRegistry())
@@ -81,9 +94,23 @@ def test_flush_counters_follow_the_stream(small, k, k_tile, nk):
     counts = {n: reg.counter(n).value for n in COUNTERS}
     assert counts == {"sellcs/width_rows": W * nk,
                       "sellcs/slots": W * C * nk,
-                      "sellcs/lanes_live": sc.nnz * nk}
+                      "sellcs/lanes_live": sc.nnz * nk,
+                      "sellcs/split_slots": sc.split_slots * nk,
+                      "sellcs/split_lanes": sc.split_lanes * nk}
     assert counts["sellcs/lanes_live"] / counts["sellcs/slots"] == \
         pytest.approx(sc.fill_ratio, rel=1e-12)
+    # a split row owns several slots; its slices and its entries, counted
+    # from the stream's arrays
+    perm = np.asarray(sc.row_perm)
+    m = sc.shape[0]
+    owned = np.bincount(perm, minlength=m + 1)[:m]
+    virtual = np.flatnonzero(np.isin(perm, np.flatnonzero(owned > 1)))
+    widths = np.diff(np.asarray(sc.slice_ptr))
+    assert counts["sellcs/split_slots"] == nk * C * int(
+        widths[np.unique(virtual // C)].sum())
+    assert counts["sellcs/split_lanes"] == nk * int(
+        np.asarray(sc.row_len)[virtual].sum())
+    assert (counts["sellcs/split_lanes"] > 0) == split
     assert {n: reg.histogram(n).count for n in FLUSH} == dict.fromkeys(
         FLUSH, 1)
     dense = np.asarray(sc.to_coo().todense())

@@ -131,8 +131,8 @@ def _skewed(name):
 ])
 def test_single_device_plan_takes_one_global_sort(name, structure,
                                                   min_ratio):
-    """The one-device operator's stream is ``coo_to_sellcs`` at σ = m,
-    array for array, and on a skewed matrix over many σ-windows it walks
+    """The one-device operator's stream is ``coo_to_sellcs`` at σ = m with
+    long rows split, array for array, and on a skewed matrix over many σ-windows it walks
     far fewer width-rows than the converter's default σ-window stream."""
     from repro.core import PlanSpec
     from repro.spmm.sellcs import DEFAULT_SIGMA_SLICES
@@ -142,7 +142,8 @@ def test_single_device_plan_takes_one_global_sort(name, structure,
         coo, PlanSpec(num_devices=1, algorithm="sellcs",
                       structure=structure), impl="ref")
     got = op.plan.matrix
-    want = M.coo_to_sellcs(coo, sigma=m, structure=structure)
+    want = M.coo_to_sellcs(coo, sigma=m, structure=structure,
+                           split_long_rows=True)
     got_arrays, got_def = jax.tree_util.tree_flatten(got)
     want_arrays, want_def = jax.tree_util.tree_flatten(want)
     assert got_def == want_def          # shape, chunk, sigma, nnz, structure
@@ -168,6 +169,170 @@ def test_global_sort_answers_equal_window_sort_bitwise(k, k_tile):
     ya = M.sellcs_spmm(window, X, k_tile=k_tile, interpret=True)
     yb = M.sellcs_spmm(glob, X, k_tile=k_tile, interpret=True)
     assert np.array_equal(np.asarray(ya), np.asarray(yb))
+
+
+# --------------------------------------------------------------------------
+# Long rows split into virtual rows (the one-device plan)
+# --------------------------------------------------------------------------
+def _hub(m, nnz, structure, seed=3):
+    """A square matrix with one row holding 40 % of the entries; in the
+    symmetric form the hub's column mirrors its row."""
+    rows, cols, vals, shape = matrices.mawi_like(m, m, nnz, 0.4, seed=seed)
+    if structure == "symmetric":
+        rows, cols, vals = (np.concatenate([rows, cols]),
+                            np.concatenate([cols, rows]),
+                            np.concatenate([vals, vals]))
+    return to_coo(rows, cols, vals, shape)
+
+
+@pytest.fixture(scope="module", params=["general", "symmetric"])
+def hub_op(request):
+    from repro.core import PlanSpec
+    coo = _hub(1500, 2400, request.param)
+    op = M.SparseOperator.from_coo(
+        coo, PlanSpec(num_devices=1, algorithm="sellcs",
+                      structure=request.param), impl="pallas_interpret")
+    return coo, op
+
+
+@pytest.mark.parametrize("k", [1, 32, 130])
+@pytest.mark.parametrize("op_", ["N", "T"])
+def test_split_operator_matches_float64_reference(hub_op, op_, k):
+    coo, op = hub_op
+    sc = op.plan.matrix
+    hub = int(np.argmax(np.bincount(np.asarray(coo.rows))))
+    assert sc.split_lanes > 0
+    assert np.sum(np.asarray(sc.row_perm) == hub) > 1     # the hub is split
+    a = np.asarray(coo.todense(), np.float64)
+    X = np.random.default_rng(k).standard_normal(
+        (coo.shape[0], k)).astype(np.float32)
+    if op_ == "N":
+        got, want = op.matmul(jnp.asarray(X)), a @ X
+    else:
+        got, want = op.rmatmul(jnp.asarray(X)), a.T @ X
+    np.testing.assert_allclose(np.asarray(got), want, rtol=RTOL, atol=ATOL)
+
+
+@pytest.mark.parametrize("structure", ["general", "symmetric"])
+def test_split_stream_round_trips_and_fills(structure):
+    coo = _hub(16000, 40000, structure)
+    m = coo.shape[0]
+    hub_len = int(np.bincount(np.asarray(coo.rows)).max())
+    sc = M.coo_to_sellcs(coo, sigma=m, structure=structure,
+                         split_long_rows=True)
+    unsplit = M.coo_to_sellcs(coo, sigma=m, structure=structure)
+    assert unsplit.split_slots == unsplit.split_lanes == 0
+    # unsplit, the hub pads its slice to its own length
+    assert unsplit.data.shape[0] >= hub_len // (2 if structure ==
+                                                "symmetric" else 1)
+    assert sc.data.shape[0] * 20 < unsplit.data.shape[0]
+    assert sc.fill_ratio >= 0.95
+    assert sc.nnz == unsplit.nnz
+    assert 0 < sc.split_lanes <= sc.split_slots
+    assert sc.split_slots % sc.chunk == 0
+    rt, want = sc.to_coo(), coo              # to_coo sorts row-major
+    got = np.lexsort((np.asarray(rt.cols), np.asarray(rt.rows)))
+    np.testing.assert_array_equal(np.asarray(rt.rows)[got],
+                                  np.asarray(want.rows))
+    np.testing.assert_array_equal(np.asarray(rt.cols)[got],
+                                  np.asarray(want.cols))
+    np.testing.assert_array_equal(np.asarray(rt.data)[got],
+                                  np.asarray(want.data))
+
+
+def test_split_leaves_rows_of_the_cap_whole():
+    """L* is the 128th longest row's length: a row of exactly L* entries
+    stays one row, a longer one becomes ⌈L/L*⌉ near-equal pieces."""
+    m = 300
+    lens = np.zeros(m, np.int64)
+    lens[10:137] = 5                     # 127 rows of 5
+    lens[200] = 5                        # the 128th longest: L* = 5
+    lens[7] = 12                         # split into 4 + 4 + 4
+    rows = np.repeat(np.arange(m), lens)
+    cols = np.concatenate([np.arange(n) * 7 % m for n in lens])
+    vals = np.random.default_rng(0).standard_normal(rows.size)
+    coo = to_coo(rows, cols, vals.astype(np.float32), (m, m))
+    sc = M.coo_to_sellcs(coo, sigma=m, split_long_rows=True)
+    slots = np.bincount(np.asarray(sc.row_perm), minlength=m + 1)[:m]
+    assert slots[7] == 3
+    np.testing.assert_array_equal(slots[lens == 5], 1)
+    row_len = np.asarray(sc.row_len)
+    assert sorted(row_len[np.asarray(sc.row_perm) == 7]) == [4, 4, 4]
+    assert row_len.max() == 5
+    assert sc.split_lanes == 12
+    X = jnp.asarray(np.random.default_rng(1).standard_normal(
+        (m, 3)).astype(np.float32))
+    np.testing.assert_allclose(
+        np.asarray(M.sellcs_spmm(sc, X, interpret=True)),
+        np.asarray(coo.todense()) @ np.asarray(X), rtol=RTOL, atol=ATOL)
+
+
+def test_split_leaves_a_mesh_stream_bitwise():
+    """No row of a 5-point stencil is longer than the 128th longest, so
+    the split stream is the unsplit one, array for array."""
+    coo = to_coo(*matrices.mesh2d(64, seed=0))
+    m = coo.shape[0]
+    got = M.coo_to_sellcs(coo, sigma=m, split_long_rows=True)
+    want = M.coo_to_sellcs(coo, sigma=m)
+    got_arrays, got_def = jax.tree_util.tree_flatten(got)
+    want_arrays, want_def = jax.tree_util.tree_flatten(want)
+    assert got_def == want_def
+    for a, b in zip(got_arrays, want_arrays):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_mesh_partitioners_refuse_a_split_stream():
+    from repro.spmm.distributed import (partition_sellcs_nnz,
+                                        partition_sellcs_rows)
+    coo = _hub(1500, 2400, "general")
+    sc = M.coo_to_sellcs(coo, c=16, split_long_rows=True)
+    assert sc.split_lanes > 0
+    for part in (partition_sellcs_rows, partition_sellcs_nnz):
+        with pytest.raises(ValueError, match="split rows"):
+            part(sc, 2)
+
+
+def test_mesh_plans_get_the_unsplit_stream():
+    """``_realize_mesh`` converts without the split: a mesh plan's stream
+    is the one the partitioners took before it, and answers as the
+    oracle does. Runs on two host devices in a subprocess (the device
+    count is fixed when JAX starts)."""
+    import os
+    import subprocess
+    import sys
+    code = """
+import jax, numpy as np, jax.numpy as jnp
+from repro.core import PlanSpec, to_coo
+from repro.data import matrices
+from repro.spmm import SparseOperator, coo_to_sellcs, spmm_coo
+coo = to_coo(*matrices.mawi_like(1500, 1500, 2400, 0.4, seed=3))
+X = jnp.asarray(np.random.default_rng(0).standard_normal(
+    (1500, 4)).astype(np.float32))
+for sched in ("row", "merge"):
+    op = SparseOperator.from_coo(coo, PlanSpec(
+        num_devices=2, mesh_shape=(2, 1), schedule=sched, num_chunks=1,
+        compact_x=False, algorithm="sellcs"), impl="ref")
+    sc = op.plan.local_matrix
+    want = coo_to_sellcs(coo, c=sc.chunk)
+    assert sc.split_lanes == 0, sched
+    a, da = jax.tree_util.tree_flatten(sc)
+    b, db = jax.tree_util.tree_flatten(want)
+    assert da == db, sched
+    assert all(np.array_equal(np.asarray(u), np.asarray(v))
+               for u, v in zip(a, b)), sched
+    np.testing.assert_allclose(np.asarray(op.matmul(X)),
+                               np.asarray(spmm_coo(coo, X)),
+                               rtol=1e-4, atol=1e-4)
+print("mesh streams unsplit")
+"""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, PYTHONPATH=os.path.join(root, "src"))
+    env["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=2 "
+                        + env.get("XLA_FLAGS", ""))
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-3000:]
+    assert "mesh streams unsplit" in out.stdout
 
 
 # --------------------------------------------------------------------------
