@@ -388,6 +388,138 @@ def test_sellcs_slots_gather_kernel_edge_streams():
                                    rtol=1e-5, atol=1e-5)
 
 
+def _resident_stream(case):
+    """A width-row stream of the one-device plan (σ = m, long rows split)
+    with one edge the resident-X kernel has to get right, and the X width
+    it multiplies."""
+    rng = np.random.default_rng(13)
+    c, m, n = 16, 300, 300
+    if case == "random_revisits":
+        # slice ids that jump back and forth, as no converter makes them
+        W, c, S, n = 150, 8, 5, 1100
+        data = rng.standard_normal((W, c)).astype(np.float32)
+        data[rng.random((W, c)) < 0.3] = 0.0
+        cols = rng.integers(0, n, (W, c)).astype(np.int32)
+        so = rng.integers(0, S, W).astype(np.int32)
+        return data, cols, so, S, c, rng.standard_normal(
+            (n, 1)).astype(np.float32)
+    if case == "split_hub":
+        coo = _hub(700, 6000, "general")
+        m = n = 700
+    elif case == "short_stream":                 # W under one SMEM block
+        c = 128
+        coo = to_coo(*matrices.uniform(200, 200, 500, seed=1))
+        m = n = 200
+    elif case == "n_not_lane_multiple":
+        n = 333
+        coo = to_coo(*matrices.uniform(m, n, 3000, seed=2))
+    elif case == "empty_rows":                   # odd rows hold nothing
+        r, cl, v, _ = matrices.uniform(m // 2, n, 2500, seed=3)
+        coo = to_coo(2 * r, cl, v, (m, n))
+    else:
+        coo = to_coo(*matrices.uniform(m, n, 3000, seed=4))
+    sc = M.coo_to_sellcs(coo, c=c, sigma=m, split_long_rows=True)
+    data, cols, so = (np.asarray(sc.data), np.asarray(sc.cols),
+                      np.asarray(sc.slice_of))
+    S = sc.num_slices
+    if case in ("padding_revisit", "all_padding_tail"):
+        # padding width-rows (data == 0) aimed at slice 0 after the rest,
+        # as shards pad; or a whole grid step and more at the last slice
+        pad = 5 if case == "padding_revisit" else 40
+        data = np.concatenate([data, np.zeros((pad, c), data.dtype)])
+        cols = np.concatenate([cols, np.zeros((pad, c), cols.dtype)])
+        so = np.concatenate([so, np.full(pad, 0 if pad == 5 else S - 1,
+                                         so.dtype)])
+    if case == "split_hub":
+        assert sc.split_lanes > 0
+    x = rng.standard_normal((n, 1)).astype(np.float32)
+    return data, cols, so, S, c, x
+
+
+@pytest.mark.parametrize("case", ["split_hub", "short_stream",
+                                  "n_not_lane_multiple", "empty_rows",
+                                  "padding_revisit", "all_padding_tail",
+                                  "random_revisits"])
+def test_resident_kernel_equals_dma_kernel_bitwise(case):
+    """The resident-X kernel adds the same float32 products in the same
+    width-row order as the DMA kernel, so its slots are the DMA kernel's
+    to the bit (``array_equal`` counts -0.0 equal to 0.0), and the jnp
+    twin's wherever each slice is one run of width-rows (padding aside);
+    where a slice comes back with products, both kernels add it into Y
+    as a second partial sum, which the twin's scatter does not."""
+    from repro.spmm.kernels import (pack_x, sellcs_slots,
+                                    sellcs_slots_resident)
+    from repro.spmm.reference import sellcs_slots_ref
+    data, cols, so, S, C, x = _resident_stream(case)
+    args = (jnp.asarray(data), jnp.asarray(cols), jnp.asarray(so))
+    y = sellcs_slots_resident(*args, pack_x(jnp.asarray(x)), num_slices=S,
+                              chunk=C, interpret=True)
+    assert y.shape == (S, C)
+    y = np.asarray(y).reshape(-1, 1)
+    dma = sellcs_slots(*args, jnp.asarray(x), num_slices=S, chunk=C,
+                       k_tile=1, interpret=True)
+    ref = sellcs_slots_ref(*args, jnp.asarray(x), num_slices=S, chunk=C)
+    assert np.array_equal(y, np.asarray(dma))
+    if case == "random_revisits":
+        np.testing.assert_allclose(y, np.asarray(ref), rtol=1e-5, atol=1e-5)
+    else:
+        assert np.array_equal(y, np.asarray(ref))
+    assert np.any(y != 0)
+
+
+MIB = 2 ** 20
+
+
+@pytest.mark.parametrize("n,k,chunk,resident", [
+    (4_194_304, 1, 128, True),        # poisson2d_2048, mawi22: 16 MiB of X
+    (2_097_152, 1, 128, True),        # kron21 at k = 1
+    (2_097_152, 32, 128, False),      # kron21.clients32: 256 MiB of X
+    (2_097_152, 2, 128, False),       # only a single vector is packed
+    (2 ** 25, 1, 128, False),         # X alone fills the 128 MiB
+    (4_194_304, 1, 64, False),        # slices narrower than a lane
+])
+def test_x_resident_rule_reads_shapes(n, k, chunk, resident):
+    """The resident kernel is taken by shape alone, against a v5e's 128 MiB
+    of VMEM; nothing resides where the device has no VMEM."""
+    from repro.spmm.kernels import sellcs_resident_vmem_bytes, x_resident
+    assert x_resident(n, k, 128 * MIB, chunk=chunk) is resident
+    assert not x_resident(n, k, 0, chunk=chunk)
+    if resident:
+        assert n * 4 < sellcs_resident_vmem_bytes(n) <= 128 * MIB
+
+
+@pytest.mark.parametrize("k,capacity,resident", [
+    (1, 128 * MIB, True), (2, 128 * MIB, False), (1, 0, False)])
+def test_sellcs_spmm_counts_resident_lanes(monkeypatch, k, capacity,
+                                           resident):
+    """``sellcs/x_resident_lanes`` counts nnz per pass on the resident path
+    and 0 on the DMA path; both paths give the dense product."""
+    from repro.obs import MetricRegistry, install, uninstall
+    from repro.spmm import kernels as K
+    monkeypatch.setattr(K, "vmem_capacity_bytes", lambda: capacity)
+    coo = _hub(500, 2000, "general")
+    sc = M.coo_to_sellcs(coo, sigma=500, split_long_rows=True)
+    X = jnp.asarray(np.random.default_rng(3).standard_normal(
+        (500, k)).astype(np.float32))
+    calls = []
+    real = K.sellcs_slots_resident
+    monkeypatch.setattr(K, "sellcs_slots_resident",
+                        lambda *a, **kw: calls.append(1) or real(*a, **kw))
+    reg = install(MetricRegistry())
+    try:
+        y = K.sellcs_spmm(sc, X, k_tile=k, interpret=True)
+    finally:
+        uninstall()
+    assert bool(calls) is resident
+    lanes = reg.counter("sellcs/lanes_live").value
+    assert lanes == sc.nnz
+    assert reg.counter("sellcs/x_resident_lanes").value == (
+        lanes if resident else 0)
+    np.testing.assert_allclose(np.asarray(y),
+                               np.asarray(coo.todense()) @ np.asarray(X),
+                               rtol=RTOL, atol=ATOL)
+
+
 def test_sellcs_slots_rejects_unaligned_widths():
     """``choose_k_tile`` owns the width: ``sellcs_slots`` pads nothing, so
     an X narrower than a whole number of k-tiles, or (for Mosaic) a k-tile

@@ -89,6 +89,40 @@ def test_sellcs_spmm_flush_compiles(one_chip):
     _assert_kernel(f.lower(sc, s((N, 32), jnp.float32)).compile())
 
 
+# the one-chip cells with the most rows: poisson2d_2048 and mawi22
+N_CELL = 2 ** 22
+
+
+def test_sellcs_slots_resident_compiles_at_cell_shape(one_chip):
+    """The resident-X kernel at k = 1: 16 MiB of packed X held in VMEM
+    under the kernel's own scoped limit, Y lane-dense."""
+    s = lambda shape, dt: _sds(shape, dt, one_chip)
+    f = jax.jit(lambda d, c, so, x: K.sellcs_slots_resident(
+        d, c, so, K.pack_x(x), num_slices=N_CELL // C, chunk=C))
+    _assert_kernel(f.lower(s((W, C), jnp.float32), s((W, C), jnp.int32),
+                           s((W,), jnp.int32),
+                           s((N_CELL, 1), jnp.float32)).compile())
+
+
+def test_sellcs_spmm_resident_flush_compiles(one_chip, monkeypatch):
+    """The k = 1 flush multiply as a v5e (128 MiB of VMEM) takes it:
+    packing, the resident kernel and the σ-unpermute."""
+    monkeypatch.setattr(K, "vmem_capacity_bytes", lambda: 128 * 2 ** 20)
+    s = lambda shape, dt: _sds(shape, dt, one_chip)
+    sc = SellCS(data=s((W, C), jnp.float32), cols=s((W, C), jnp.int32),
+                slice_ptr=s((S + 1,), jnp.int32), slice_of=s((W,), jnp.int32),
+                row_perm=s((S * C,), jnp.int32),
+                row_len=s((S * C,), jnp.int32), diag=None, shape=(N, N),
+                chunk=C, sigma=N, nnz=48_097_363)
+    calls = []
+    real = K.sellcs_slots_resident
+    monkeypatch.setattr(K, "sellcs_slots_resident",
+                        lambda *a, **kw: calls.append(1) or real(*a, **kw))
+    f = jax.jit(lambda m, x: K.sellcs_spmm(m, x))
+    _assert_kernel(f.lower(sc, s((N, 1), jnp.float32)).compile())
+    assert calls
+
+
 def _mesh4(topo):
     mesh = jax.sharding.Mesh(np.array(topo.devices[:4]), ("data",))
     return mesh, lambda *spec: NamedSharding(mesh, P(*spec))
