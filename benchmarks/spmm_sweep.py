@@ -78,9 +78,13 @@ def sweep_matrix(name: str, coo, ks, impl: str, reps: int, csv) -> None:
         pass                       # too sparse for dense mini-tiles
     rng = np.random.default_rng(0)
     for fmt, mat in formats.items():
+        # the Pallas kernels are the SELL-C-σ family: the other formats'
+        # rows stay on the XLA reference
+        fmt_impl = ("ref" if impl.startswith("pallas") and fmt != "sellcs"
+                    else impl)
         for k in ks:
             X = jnp.asarray(rng.standard_normal((n, k)).astype(np.float32))
-            sec = harness.time_fn(lambda: spmm(mat, X, impl=impl),
+            sec = harness.time_fn(lambda: spmm(mat, X, impl=fmt_impl),
                                   reps=reps, warmup=1)
             flops = 2.0 * nnz * k
             gflops = flops / sec / 1e9

@@ -4,7 +4,8 @@
   2. inspect its stats and let the paper's §7 selector pick an algorithm;
   3. convert (the paper's conversion phase) and multiply (9 algorithms);
   4. validate everything against the dense oracle;
-  5. show the TPU tiled format + Pallas kernel (interpret mode on CPU).
+  5. show SELL-C-σ, the format of the Pallas kernel family, through the
+     one SpMM dispatcher (interpret mode on CPU).
 
 Run:  PYTHONPATH=src python examples/quickstart.py
 """
@@ -14,7 +15,7 @@ import jax.numpy as jnp
 from repro.core import (ALGORITHM_SPECS, MachineSpec, convert, matrix_stats,
                         select_algorithm, spmv, spmv_dense_oracle, to_coo)
 from repro.data import matrices
-from repro.kernels import coo_to_tiled, ops
+from repro.spmm import coo_to_sellcs, spmm
 
 # 1. an unstructured matrix (LiveJournal-like power-law rows)
 rows, cols, vals, shape = matrices.powerlaw(4096, 4096, 65536, seed=0)
@@ -46,11 +47,12 @@ for algo, spec in ALGORITHM_SPECS.items():
         if hasattr(mat, "storage_bytes") else ""
     print(f"  {algo:8s} ok (max err {err:.2e}){extra}  [{spec.note}]")
 
-# 5. the TPU compute format + Pallas kernel (interpret mode on CPU)
-ts = coo_to_tiled(coo, "csbh", beta=256)
-xsw, ysw = ts.window_switches()
-print(f"tiled: {ts.num_tiles} 8x128 tiles, fill={ts.fill_ratio:.3f}, "
-      f"window switches x={xsw} y={ysw}")
-y_k = ops.bsr_spmv(ts, x, interpret=True)
-print(f"pallas bsr_spmv max err: {float(jnp.max(jnp.abs(y_k - y_ref))):.2e}")
+# 5. SELL-C-σ + its Pallas kernel (interpret mode on CPU); SpMV is SpMM's
+#    k = 1 column
+sc = coo_to_sellcs(coo)
+print(f"sellcs: {sc.num_slices} slices of {sc.chunk} rows, "
+      f"{sc.data.shape[0]} width-rows")
+y_k = spmm(sc, x, impl="pallas_interpret")
+print(f"pallas sellcs spmv max err: "
+      f"{float(jnp.max(jnp.abs(y_k - y_ref))):.2e}")
 print("quickstart OK")

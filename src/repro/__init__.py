@@ -11,9 +11,11 @@ Module map
 ``repro.spmm``       the multi-RHS SpMM engine: SELL-C-σ storage
                      (``sellcs``; ``structure="symmetric"`` stores one
                      triangle + diagonal), pure-jnp oracles
-                     (``reference``), tiled Pallas kernels with a k-tile
-                     grid dimension plus the scatter-accumulate transpose
-                     kernel (``kernels``), request batching for the serve
+                     (``reference``), the one Pallas kernel family —
+                     SELL-C-σ forward (HBM-gather and VMEM-resident
+                     k = 1) and scatter-accumulate transpose, each with a
+                     k-tile grid dimension (``kernels``) — behind the one
+                     dispatcher ``spmm``, request batching for the serve
                      path (``batching``), the shard_map mesh schedules —
                      row bands / merge spans over the slice stream, both
                      op-aware (``op="N"|"T"``, ``distributed``) — and
@@ -28,10 +30,9 @@ Module map
                      ``redeal_sellcs``, LRU eviction under a
                      ``max_bytes`` storage budget. SpMV is the k = 1
                      special case.
-``repro.kernels``    Pallas TPU kernels for the single-vector compute
-                     paths: blocked SpMV (``bsr_spmv``), merge-path SpMV
-                     (``merge_spmv``), MoE grouped GEMM, plus the
-                     TiledSparse 8x128 mini-tile compute format.
+``repro.kernels``    the TiledSparse 8x128 mini-tile blocked format and
+                     the merge-path plan, each with an XLA reference, and
+                     the MoE grouped-GEMM Pallas kernel.
 ``repro.roofline``   roofline terms from compiled HLO + the SpMM intensity
                      model that picks k-tiles.
 ``repro.data``       synthetic matrix generators matched to the paper's

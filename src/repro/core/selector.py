@@ -458,10 +458,10 @@ def distributed_schedule_grid(num_devices: int = 1,
                     for mesh in meshes for nc in chunks)
     return grid
 
-# Formats with an executable mesh multiply: "parcrs" drives the ShardedCOO
-# path in core.distributed (its nonzero stream is the row-sorted COO both
-# partitioners consume), "sellcs" the slice-stream path in
-# repro.spmm.distributed. Other paper families are deliberately absent —
+# Formats the mesh grid scores: "sellcs" runs the slice-stream schedules of
+# repro.spmm.distributed; "parcrs" is scored as the row-sorted baseline
+# every distributed time is priced against, but SparseOperator's mesh plan
+# runs SELL-C-σ only. Other paper families are deliberately absent —
 # recommending a format the mesh cannot run is worse than a slightly
 # coarser prior.
 DISTRIBUTED_ALGOS = ("parcrs", "sellcs")

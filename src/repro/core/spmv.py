@@ -3,8 +3,9 @@
 Every storage format lowers to the same contraction y[r] += v * x[c]; what the
 paper's nine algorithms change is *storage layout*, *traversal order* and
 *scheduling*. On TPU the jnp implementations below are the correctness oracles
-and the XLA baseline; the performance path is `repro.kernels` (Pallas) and the
-distributed path is `core.distributed` (shard_map).
+and the XLA baseline. The performance path is the SELL-C-σ Pallas family behind
+`repro.spmm.spmm`, where SpMV is the k = 1 column, and the distributed path is
+`repro.spmm.distributed` (shard_map).
 """
 from __future__ import annotations
 
@@ -108,26 +109,18 @@ def spmv(mat: Matrix, x: Array, impl: str = "auto") -> Array:
     """Multiply. impl in {"auto", "ref", "pallas", "pallas_interpret"}.
 
     "auto" uses the Pallas kernel where one lowers on the running TPU
-    (``repro.spmm.kernels.resolve_impl``: SELL-C-σ), otherwise the XLA
-    reference. Kernels are imported lazily to keep the core
+    (``repro.spmm.kernels.resolve_impl``: SELL-C-σ), otherwise the
+    per-format XLA reference below. The Pallas impls delegate to
+    ``repro.spmm.spmm``, the one dispatcher that picks a kernel; it takes
+    a SELL-C-σ matrix only. Imported lazily to keep the core
     dependency-light."""
     from repro.kernels.tiling import TiledSparse
     from repro.spmm.kernels import resolve_impl   # late import: core <- spmm
     from repro.spmm.sellcs import SellCS
     impl = resolve_impl(impl, mat)
     if impl in ("pallas", "pallas_interpret"):
-        interpret = impl == "pallas_interpret"
-        from repro.kernels import ops as kops
-        if isinstance(mat, TiledSparse):
-            return kops.bsr_spmv(mat, x, interpret=interpret)
-        if isinstance(mat, CSR):
-            return kops.merge_spmv(mat, x, interpret=interpret)
-        if isinstance(mat, SellCS):
-            from repro.spmm.kernels import sellcs_spmm
-            return sellcs_spmm(mat, x[:, None], interpret=interpret)[:, 0]
-        raise TypeError(
-            f"no kernel path for {type(mat).__name__}; convert with "
-            "repro.kernels.coo_to_tiled for the blocked kernel")
+        from repro.spmm import spmm
+        return spmm(mat, x, impl=impl)
     if isinstance(mat, TiledSparse):
         from repro.kernels.ref import bsr_spmv_ref
         return bsr_spmv_ref(mat, x)

@@ -1,7 +1,8 @@
-"""repro.kernels — Pallas TPU kernels for the perf-critical compute paths:
-blocked SpMV (bsr_spmv), merge-path SpMV (merge_spmv) and the MoE grouped
-GEMM (moe_group_matmul). Each ships a jit wrapper (ops) and a pure-jnp
-oracle (ref)."""
+"""repro.kernels — the TiledSparse blocked storage format (8x128 dense
+mini-tiles, ``tiling``) and the merge-path plan (``merge_spmv``), each with
+an XLA reference path (``ref``), and the MoE grouped-GEMM Pallas kernel
+(``ops``). The sparse Pallas kernels are one SELL-C-σ family in
+``repro.spmm.kernels``."""
 from . import ops, ref
 from .tiling import TILE_C, TILE_R, TiledSparse, coo_to_tiled
 from .merge_spmv import MergePlan, merge_plan

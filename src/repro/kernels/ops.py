@@ -1,53 +1,16 @@
-"""Public jit'd wrappers around the Pallas kernels.
+"""Public jit'd wrapper around the MoE grouped-GEMM Pallas kernel.
 
 ``interpret=True`` executes kernel bodies in Python on CPU (how this repo
 validates them); on a TPU backend pass interpret=False for Mosaic lowering.
 """
 from __future__ import annotations
 
-from typing import Optional
-
 import jax
 import jax.numpy as jnp
 
-from repro.core.formats import CSR
-from . import merge_spmv as _merge
 from . import moe_group_matmul as _moe
-from .bsr_spmv import bsr_spmm as _bsr_spmm
-from .bsr_spmv import bsr_spmv as _bsr_spmv
-from .tiling import TiledSparse
 
 M_TILE = _moe.M_TILE
-
-
-def bsr_spmv(ts: TiledSparse, x: jax.Array, *, interpret: bool = False,
-             tiles_per_step: int = 8) -> jax.Array:
-    return _bsr_spmv(ts, x, tiles_per_step=tiles_per_step,
-                     interpret=interpret)
-
-
-def bsr_spmm(ts: TiledSparse, x: jax.Array, *, interpret: bool = False,
-             tiles_per_step: int = 8) -> jax.Array:
-    return _bsr_spmm(ts, x, tiles_per_step=tiles_per_step,
-                     interpret=interpret)
-
-
-def merge_spmv(csr: CSR, x: jax.Array, *, num_spans: Optional[int] = None,
-               plan: Optional[_merge.MergePlan] = None,
-               interpret: bool = False) -> jax.Array:
-    """Merge-path SpMV. Build the plan once (convert time) and reuse it —
-    that is the paper's conversion/multiplication split."""
-    m, n = csr.shape
-    if plan is None:
-        if num_spans is None:
-            num_spans = _merge.default_num_spans(m, csr.nnz)
-        plan = _merge.merge_plan(csr, num_spans)
-    np_ = -(-n // 128) * 128
-    x_pad = jnp.zeros((np_,), x.dtype).at[:n].set(x)
-    partials = _merge.merge_spmv_partials(
-        plan.cols, plan.vals, plan.seg, x_pad, r_width=plan.r_width,
-        interpret=interpret)                       # (P, R)
-    return _merge.carry_out_fixup(partials, plan.row_starts, m)
 
 
 def moe_group_matmul(tokens: jax.Array, weights: jax.Array,

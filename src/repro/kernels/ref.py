@@ -1,4 +1,6 @@
-"""Pure-jnp oracles for every kernel in repro.kernels."""
+"""Pure-jnp references: the XLA paths of the TiledSparse blocked format
+(single- and multi-RHS) and of the merge-path plan, and the oracle of the
+MoE grouped GEMM kernel."""
 from __future__ import annotations
 
 import jax
@@ -9,7 +11,8 @@ from .tiling import TILE_C, TILE_R, TiledSparse
 
 @jax.jit
 def bsr_spmv_ref(ts: TiledSparse, x: jax.Array) -> jax.Array:
-    """Oracle for bsr_spmv: vmap the per-tile matvec, scatter-add slabs."""
+    """TiledSparse SpMV in XLA: vmap the per-tile matvec, scatter-add
+    slabs."""
     m, n = ts.shape
     mp, np_ = ts.padded_shape()
     x_pad = jnp.zeros((np_,), x.dtype).at[:n].set(x)
@@ -19,13 +22,6 @@ def bsr_spmv_ref(ts: TiledSparse, x: jax.Array) -> jax.Array:
     y = jnp.zeros((mp // TILE_R, TILE_R), jnp.float32)
     y = y.at[ts.tile_rows].add(contrib)
     return y.reshape(mp)[:m]
-
-
-@jax.jit
-def merge_spmv_ref(csr, x: jax.Array) -> jax.Array:
-    """Oracle for merge_spmv == plain CSR SpMV."""
-    from repro.core.spmv import spmv_csr
-    return spmv_csr(csr, x)
 
 
 def moe_group_matmul_ref(tokens: jax.Array, weights: jax.Array,
@@ -49,10 +45,10 @@ import functools
 @functools.partial(jax.jit, static_argnames=("r_width", "m"))
 def merge_spmv_xla(cols, vals, seg, row_starts, x_pad: jax.Array, *,
                    r_width: int, m: int) -> jax.Array:
-    """XLA realization of the merge-path algorithm from the same MergePlan
-    the Pallas kernel uses (vmap over spans + segment reduction + the
-    sequential carry-out fixup). Used for wall-clock algorithm sweeps on
-    CPU (Fig 6.1 analogue)."""
+    """XLA realization of the merge-path algorithm from a ``MergePlan``
+    (vmap over spans + segment reduction + the sequential carry-out
+    fixup). Used for wall-clock algorithm sweeps on CPU (Fig 6.1
+    analogue)."""
     xs = x_pad[cols]                               # [P, D] gather
     prod = vals.astype(jnp.float32) * xs.astype(jnp.float32)
     partials = jax.vmap(
@@ -65,7 +61,7 @@ def merge_spmv_xla(cols, vals, seg, row_starts, x_pad: jax.Array, *,
 
 @jax.jit
 def bsr_spmm_ref(ts: TiledSparse, x: jax.Array) -> jax.Array:
-    """Oracle for bsr_spmm (multi-RHS)."""
+    """TiledSparse SpMM (multi-RHS) in XLA, as ``bsr_spmv_ref``."""
     m, n = ts.shape
     mp, np_ = ts.padded_shape()
     R = x.shape[1]

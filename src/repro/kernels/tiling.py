@@ -1,32 +1,29 @@
-"""TiledSparse — the TPU compute format for unstructured SpMV.
+"""TiledSparse — a blocked storage format of dense 8x128 mini-tiles, with
+an XLA reference multiply (``ref.bsr_spmv_ref`` / ``ref.bsr_spmm_ref``).
 
-Hardware adaptation (DESIGN.md §2): the paper's CPU algorithms do per-nonzero
-``y[r] += v * x[c]`` — a scatter/gather pattern with no efficient TPU
-lowering (the VPU has no cheap vector scatter; the MXU wants dense tiles).
-The TPU dialect of the paper's *blocked* formats is therefore hierarchical:
+The paper's *blocked* formats, in a hierarchical TPU dialect:
 
   level 0  (paper: sparse block, cache-sized)   macro block, beta x beta
   level 1  (new, hardware)                      dense 8 x 128 mini-tiles
                                                  (VREG sublane x lane shape)
 
 Only nonempty mini-tiles are stored (dense, zero-filled). SpMV per mini-tile
-is a dense (8,128) @ (128,) matvec — pure MXU/VPU work, no scatter. What
-survives of each paper algorithm:
+is a dense (8,128) @ (128,) matvec — no scatter. What survives of each paper
+algorithm:
 
   * blocking       -> beta chooses the x/y slab reuse distance;
   * nonzero order  -> the mini-tile visit order (row / Morton / Hilbert at
                       both macro and in-macro level) controls how often the
-                      x- and y-windows move => Pallas elides copies for
-                      consecutive same-index windows (the cache-reuse story,
+                      x- and y-windows move (the cache-reuse story,
                       measurable as window-switch counts);
-  * load balancing -> uniform work quanta (every tile = same FLOPs) plus
-                      merge-path spans over tiles; a single dense row is
-                      split across many tiles (the mawi fix).
+  * load balancing -> uniform work quanta (every tile = same FLOPs); a
+                      single dense row is split across many tiles (the mawi
+                      fix).
 
 The price is fill-in: ``fill_ratio`` = nnz / (1024 * num_tiles). For very
-sparse matrices fill-in makes the XLA gather path cheaper — the paper's
-density-dependent algorithm choice, reappearing on TPU (see selector +
-EXPERIMENTS).
+sparse matrices fill-in makes the gather path cheaper — the paper's
+density-dependent algorithm choice (see selector). The Pallas path on the
+chip is SELL-C-σ (``repro.spmm``); no kernel reads this format.
 """
 from __future__ import annotations
 
@@ -44,18 +41,6 @@ from repro.core.mergepath import balanced_row_bands
 
 TILE_R = 8      # sublane dimension
 TILE_C = 128    # lane dimension
-
-
-def interpret_only(kernel: str, interpret: bool) -> None:
-    """Refuse a Pallas kernel that does not lower on Mosaic (a
-    VMEM-resident ``[n, ·]`` slab, an in-kernel ``jnp.take`` gather, or a
-    sub-tile block shape): it runs in interpret mode only, and the TPU path
-    raises here instead of silently running something else."""
-    if not interpret:
-        raise NotImplementedError(
-            f"{kernel} does not lower on Mosaic (TPU); run it with "
-            "interpret=True (impl='pallas_interpret'), use impl='ref', or "
-            "convert to SELL-C-σ (coo_to_sellcs), whose kernel does")
 
 
 def _morton_key_np(rows, cols, bits):

@@ -86,7 +86,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.core.distributed import _check_devices
 from repro.core.mergepath import balanced_row_bands
 from repro.obs import maybe_block, span
 from .kernels import (LANE, choose_k_tile, sellcs_slots, sellcs_slots_chunk,
@@ -171,6 +170,11 @@ class ShardedSellCS(NamedTuple):
                 if opt is not None:
                     total += opt.nbytes
         return int(total)
+
+
+def _check_devices(num_devices: int) -> None:
+    if num_devices < 1:
+        raise ValueError(f"num_devices must be >= 1, got {num_devices}")
 
 
 def _check_unsplit(sc: SellCS) -> None:

@@ -1,26 +1,24 @@
-"""Tiled Pallas SpMM kernels — CSR (merge-path), blocked (TiledSparse) and
-SELL-C-σ, each with a column-block (k-tile) grid dimension.
+"""The Pallas SpMM kernels: one SELL-C-σ family, each kernel with a
+column-block (k-tile) grid dimension. SpMV is its k = 1 case.
 
-The SELL-C-σ forward kernel (``sellcs_slots``) is the one that lowers on
-Mosaic and serves the TPU path, on one device and in every distributed
-schedule. It keeps X and the slot output in HBM: each grid step DMAs the
-X rows its width-rows name into VMEM and adds finished slices into Y, so
-its VMEM footprint is fixed by the k-tile and matrices far larger than
-VMEM run. The k-tile is the *leading, parallel* grid dimension: k-tiles
-touch disjoint X/Y columns, while the matrix stream stays "arbitrary"
-(sequential accumulate). Its k = 1 variant on one device,
-``sellcs_slots_resident``, walks the same stream with X copied into VMEM
-once per pass, where X fits (``x_resident``): a gather is then a vector
-load, not a DMA.
+The SELL-C-σ forward kernel (``sellcs_slots``) serves the TPU path, on
+one device and in every distributed schedule. It keeps X and the slot
+output in HBM: each grid step DMAs the X rows its width-rows name into
+VMEM and adds finished slices into Y, so its VMEM footprint is fixed by
+the k-tile and matrices far larger than VMEM run. The k-tile is the
+*leading, parallel* grid dimension: k-tiles touch disjoint X/Y columns,
+while the matrix stream stays "arbitrary" (sequential accumulate). Its
+k = 1 variant on one device, ``sellcs_slots_resident``, walks the same
+stream with X copied into VMEM once per pass, where X fits
+(``x_resident``): a gather is then a vector load, not a DMA.
 
-The CSR merge and blocked kernels, and the transpose kernel beyond a
-VMEM-sized n, keep whole ``[n, KT]`` slabs VMEM-resident and gather with an
-in-kernel ``jnp.take``, which Mosaic refuses: they run in interpret mode
-only, and raise for ``interpret=False``.
+The transpose kernel (``sellcs_slots_t``) keeps whole ``[n, KT]`` slabs
+VMEM-resident; beyond a VMEM-sized n Mosaic refuses it, so there it runs
+in interpret mode only and raises for ``interpret=False``.
 
 ``choose_k_tile`` picks the widest lane-multiple KT whose VMEM working set
 fits the budget: every extra k-tile re-streams the matrix and re-issues its
-gathers. The interpret-only kernels take all k columns as one tile.
+gathers.
 """
 from __future__ import annotations
 
@@ -33,10 +31,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.convert import VMEM_BUDGET_BYTES
-from repro.core.formats import CSR
-from repro.kernels import merge_spmv as _merge
-from repro.kernels.tiling import (TILE_C, TILE_R, TiledSparse,
-                                  interpret_only)
 from repro.obs import live_registry, span
 from .sellcs import SellCS
 
@@ -78,8 +72,9 @@ def resolve_impl(impl: str, mat, op: str = "N") -> str:
     """The implementation that runs for ``impl`` on ``mat``: ``"auto"``
     is the Mosaic kernel on a TPU backend where one lowers — the SELL-C-σ
     forward pass of a general matrix — and the XLA reference everywhere
-    else. Explicit impls pass through (a kernel without a Mosaic lowering
-    then raises instead of running something else)."""
+    else. Explicit impls pass through (a format with no kernel, or a
+    kernel without a Mosaic lowering, then raises instead of running
+    something else)."""
     if impl != "auto":
         return impl
     if (jax.default_backend() == "tpu" and isinstance(mat, SellCS)
@@ -94,158 +89,6 @@ def _pad_k(x: jax.Array, kt: int) -> jax.Array:
     if kp != k:
         x = jnp.pad(x, ((0, 0), (0, kp - k)))
     return x
-
-
-# --------------------------------------------------------------------------
-# TiledSparse (blocked formats' TPU compute form) SpMM, k-tiled grid
-# --------------------------------------------------------------------------
-def _tiled_kernel(tile_rows_ref, tile_cols_ref,    # scalar prefetch (SMEM)
-                  tiles_ref, x_ref,                # VMEM in
-                  y_ref,                           # VMEM out (revisited)
-                  *, tiles_per_step: int):
-    g = pl.program_id(1)
-
-    @pl.when(g == 0)
-    def _init():
-        y_ref[...] = jnp.zeros_like(y_ref)
-
-    def body(t, _):
-        idx = g * tiles_per_step + t
-        r = tile_rows_ref[idx]
-        c = tile_cols_ref[idx]
-        tile = tiles_ref[t]                                    # (8, 128)
-        xs = x_ref[pl.ds(c * TILE_C, TILE_C), :]               # (128, KT)
-        upd = jax.lax.dot_general(
-            tile, xs.astype(tile.dtype), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)                # (8, KT)
-        cur = y_ref[pl.ds(r * TILE_R, TILE_R), :]
-        y_ref[pl.ds(r * TILE_R, TILE_R), :] = cur + upd
-        return _
-
-    jax.lax.fori_loop(0, tiles_per_step, body, None)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("k_tile", "tiles_per_step", "interpret"))
-def tiled_spmm(ts: TiledSparse, x: jax.Array, *,
-               k_tile: Optional[int] = None, tiles_per_step: int = 8,
-               interpret: bool = False) -> jax.Array:
-    """Y = A @ X over the dense-mini-tile stream, grid = (k_tiles, tile
-    batches). Serves every blocked paper format (their TPU compute form is
-    TiledSparse) and is the k-generalization of kernels.bsr_spmv."""
-    interpret_only("tiled_spmm", interpret)
-    m, n = ts.shape
-    mp, np_ = ts.padded_shape()
-    k = x.shape[1]
-    kt = k_tile or k
-    x_pad = jnp.zeros((np_, k), x.dtype).at[:n].set(x)
-    x_pad = _pad_k(x_pad, kt)
-    nk = x_pad.shape[1] // kt
-
-    T = ts.num_tiles
-    TB = tiles_per_step
-    T_pad = -(-T // TB) * TB
-    tiles, tile_rows, tile_cols = ts.tiles, ts.tile_rows, ts.tile_cols
-    if T_pad != T:
-        pad = T_pad - T
-        tiles = jnp.concatenate(
-            [tiles, jnp.zeros((pad,) + tiles.shape[1:], tiles.dtype)])
-        tile_rows = jnp.concatenate(
-            [tile_rows, jnp.zeros((pad,), tile_rows.dtype)])
-        tile_cols = jnp.concatenate(
-            [tile_cols, jnp.zeros((pad,), tile_cols.dtype)])
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(nk, T_pad // TB),
-        in_specs=[
-            pl.BlockSpec((TB, TILE_R, TILE_C), lambda j, g, *_: (g, 0, 0)),
-            pl.BlockSpec((np_, kt), lambda j, g, *_: (0, j)),
-        ],
-        out_specs=pl.BlockSpec((mp, kt), lambda j, g, *_: (0, j)),
-    )
-    y = pl.pallas_call(
-        functools.partial(_tiled_kernel, tiles_per_step=TB),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((mp, x_pad.shape[1]), jnp.float32),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
-    )(tile_rows, tile_cols, tiles, x_pad)
-    return y[:m, :k]
-
-
-# --------------------------------------------------------------------------
-# CSR merge-path SpMM, k-tiled grid
-# --------------------------------------------------------------------------
-def _merge_kernel(cols_ref, vals_ref, seg_ref, x_ref, out_ref, *,
-                  r_width: int):
-    cols = cols_ref[0]                           # (D,)
-    vals = vals_ref[0].astype(jnp.float32)       # (D,)
-    seg = seg_ref[0]                             # (D,)
-    xs = jnp.take(x_ref[...], cols, axis=0,
-                  mode="clip").astype(jnp.float32)            # (D, KT)
-    prod = vals[:, None] * xs                                  # (D, KT)
-    onehot = (seg[:, None] ==
-              jax.lax.broadcasted_iota(jnp.int32, (1, r_width), 1)
-              ).astype(jnp.float32)                            # (D, R)
-    out_ref[0] = jax.lax.dot_general(
-        onehot, prod, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)                    # (R, KT)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("r_width", "k_tile", "interpret"))
-def _merge_spmm_partials(plan_cols, plan_vals, plan_seg, x_pad, *,
-                         r_width: int, k_tile: int,
-                         interpret: bool = False):
-    interpret_only("csr_spmm", interpret)
-    P, D = plan_cols.shape
-    np_ = x_pad.shape[0]
-    nk = x_pad.shape[1] // k_tile
-    grid_spec = pl.GridSpec(
-        grid=(nk, P),
-        in_specs=[
-            pl.BlockSpec((1, D), lambda j, p: (p, 0)),
-            pl.BlockSpec((1, D), lambda j, p: (p, 0)),
-            pl.BlockSpec((1, D), lambda j, p: (p, 0)),
-            pl.BlockSpec((np_, k_tile), lambda j, p: (0, j)),
-        ],
-        out_specs=pl.BlockSpec((1, r_width, k_tile),
-                               lambda j, p: (p, 0, j)),
-    )
-    return pl.pallas_call(
-        functools.partial(_merge_kernel, r_width=r_width),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((P, r_width, x_pad.shape[1]),
-                                       jnp.float32),
-        interpret=interpret,
-    )(plan_cols, plan_vals, plan_seg, x_pad)
-
-
-def csr_spmm(csr: CSR, x: jax.Array, *,
-             plan: Optional[_merge.MergePlan] = None,
-             num_spans: Optional[int] = None,
-             k_tile: Optional[int] = None,
-             interpret: bool = False) -> jax.Array:
-    """Merge-path SpMM on flat CSR: per-span one-hot matmul produces an
-    (R, KT) partial block; the sequential carry-out fixup is a single
-    scatter-add epilogue (same plan object as the SpMV kernel — build it
-    once at convert time)."""
-    m, n = csr.shape
-    k = x.shape[1]
-    if plan is None:
-        if num_spans is None:
-            num_spans = _merge.default_num_spans(m, csr.nnz)
-        plan = _merge.merge_plan(csr, num_spans)
-    kt = k_tile or k
-    np_ = -(-n // LANE) * LANE
-    x_pad = jnp.zeros((np_, k), x.dtype).at[:n].set(x)
-    x_pad = _pad_k(x_pad, kt)
-    partials = _merge_spmm_partials(
-        plan.cols, plan.vals, plan.seg, x_pad, r_width=plan.r_width,
-        k_tile=kt, interpret=interpret)                     # (P, R, Kp)
-    return _merge.carry_out_fixup(partials, plan.row_starts, m)[:, :k]
 
 
 # --------------------------------------------------------------------------
@@ -690,8 +533,7 @@ def _sellcs_t_kernel(slice_of_ref,                # scalar prefetch (SMEM)
         xb = xs_ref[pl.ds(s * chunk, chunk), :]            # (C, KT)
         prod = (data_ref[w].astype(jnp.float32)[:, None]
                 * xb.astype(jnp.float32))                  # (C, KT)
-        # scatter to columns via one-hot contraction (MXU-friendly — the
-        # same idiom as the merge kernel's per-span row scatter)
+        # scatter to columns via one-hot contraction (MXU-friendly)
         onehot = (cols_ref[w][:, None] ==
                   jax.lax.broadcasted_iota(jnp.int32, (1, n_pad), 1)
                   ).astype(jnp.float32)                    # (C, n_pad)
@@ -701,6 +543,17 @@ def _sellcs_t_kernel(slice_of_ref,                # scalar prefetch (SMEM)
         return _
 
     jax.lax.fori_loop(0, w_tile, body, None)
+
+
+def interpret_only(kernel: str, interpret: bool) -> None:
+    """Refuse a Pallas kernel at a size where it does not lower on Mosaic
+    (a VMEM-resident ``[n, ·]`` slab past the VMEM budget): it runs in
+    interpret mode only, and the TPU path raises here instead of silently
+    running something else."""
+    if not interpret:
+        raise NotImplementedError(
+            f"{kernel} does not lower on Mosaic (TPU); run it with "
+            "interpret=True (impl='pallas_interpret') or use impl='ref'")
 
 
 @functools.partial(jax.jit, static_argnames=("n_out", "chunk", "k_tile",

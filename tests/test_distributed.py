@@ -21,33 +21,6 @@ def run_sub(code: str, devices: int = 8) -> str:
     return out.stdout
 
 
-def test_spmv_row_and_merge_distributed():
-    print(run_sub("""
-import jax, numpy as np, jax.numpy as jnp
-from repro.core import to_coo, spmv_dense_oracle
-from repro.core.distributed import (partition_rows, partition_nnz,
-                                    spmv_row_distributed,
-                                    spmv_merge_distributed)
-from repro.data import matrices
-from repro.launch.mesh import make_mesh
-mesh = make_mesh((8,), ("data",))
-for gen in [matrices.uniform(500, 430, 4000, 0),
-            matrices.mawi_like(400, 400, 3000, 0.4, 1),
-            matrices.mesh2d(21)]:
-    coo = to_coo(*gen)
-    x = jnp.asarray(np.random.default_rng(3).standard_normal(
-        coo.shape[1]).astype(np.float32))
-    yo = spmv_dense_oracle(coo, x)
-    y1 = spmv_row_distributed(partition_rows(coo, 8), x, mesh)
-    np.testing.assert_allclose(np.asarray(y1), np.asarray(yo),
-                               rtol=1e-4, atol=1e-4)
-    y2 = spmv_merge_distributed(partition_nnz(coo, 8), x, mesh)
-    np.testing.assert_allclose(np.asarray(y2), np.asarray(yo),
-                               rtol=1e-4, atol=1e-4)
-print("distributed spmv OK")
-"""))
-
-
 def test_sharded_train_step_matches_single_device():
     """Same math: 2x4 mesh train step == single-device train step."""
     print(run_sub("""

@@ -1,10 +1,12 @@
-"""Pallas kernels vs pure-jnp oracles (interpret mode), swept over
-shapes/dtypes/orderings."""
+"""The XLA paths of the TiledSparse format and of the merge-path plan
+against the plain oracles, swept over shapes/dtypes/orderings, and the MoE
+grouped-GEMM kernel against its oracle (interpret mode). The SELL-C-σ
+kernels are tested in test_spmm.py."""
 import numpy as np
 import pytest
 import jax.numpy as jnp
 
-from repro.core import coo_to_csr, spmv_dense_oracle, to_coo
+from repro.core import coo_to_csr, spmv_csr, spmv_dense_oracle, to_coo
 from repro.data import matrices
 from repro.kernels import coo_to_tiled, merge_plan, ops, ref
 
@@ -33,32 +35,17 @@ def test_bsr_spmv_vs_ref(name, gen, algo):
     coo = to_coo(*gen)
     ts = coo_to_tiled(coo, algo, beta=128)
     x = _rand_x(coo.shape[1])
-    y_ref = ref.bsr_spmv_ref(ts, x)
-    np.testing.assert_allclose(np.asarray(y_ref),
+    np.testing.assert_allclose(np.asarray(ref.bsr_spmv_ref(ts, x)),
                                np.asarray(spmv_dense_oracle(coo, x)),
                                rtol=1e-4, atol=1e-4)
-    y = ops.bsr_spmv(ts, x, interpret=True)
-    np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
-                               rtol=1e-5, atol=1e-5)
-
-
-@pytest.mark.parametrize("tiles_per_step", [1, 4, 16])
-def test_bsr_spmv_tiles_per_step(tiles_per_step):
-    coo = to_coo(*matrices.uniform(256, 256, 2000, 0))
-    ts = coo_to_tiled(coo, "csb", beta=128)
-    x = _rand_x(coo.shape[1])
-    y = ops.bsr_spmv(ts, x, interpret=True, tiles_per_step=tiles_per_step)
-    np.testing.assert_allclose(np.asarray(y),
-                               np.asarray(ref.bsr_spmv_ref(ts, x)),
-                               rtol=1e-5, atol=1e-5)
 
 
 def test_bsr_spmv_bf16_tiles():
     coo = to_coo(*matrices.uniform(256, 256, 2000, 0))
     ts = coo_to_tiled(coo, "csb", beta=128, dtype=jnp.bfloat16)
     x = _rand_x(coo.shape[1])
-    y = ops.bsr_spmv(ts, x, interpret=True)
-    yo = spmv_dense_oracle(to_coo(*matrices.uniform(256, 256, 2000, 0)), x)
+    y = ref.bsr_spmv_ref(ts, x)
+    yo = spmv_dense_oracle(coo, x)
     np.testing.assert_allclose(np.asarray(y), np.asarray(yo),
                                rtol=5e-2, atol=5e-1)
 
@@ -66,13 +53,14 @@ def test_bsr_spmv_bf16_tiles():
 @pytest.mark.parametrize("name,gen", SHAPES, ids=[s[0] for s in SHAPES])
 @pytest.mark.parametrize("spans", [4, 32])
 def test_merge_spmv_vs_ref(name, gen, spans):
+    """The merge-path plan, multiplied in XLA, equals plain CSR SpMV."""
     coo = to_coo(*gen)
     csr = coo_to_csr(coo)
     x = _rand_x(coo.shape[1])
     plan = merge_plan(csr, spans)
-    y = ops.merge_spmv(csr, x, plan=plan, interpret=True)
-    np.testing.assert_allclose(np.asarray(y),
-                               np.asarray(ref.merge_spmv_ref(csr, x)),
+    y = ref.merge_spmv_xla(plan.cols, plan.vals, plan.seg, plan.row_starts,
+                           x, r_width=plan.r_width, m=coo.shape[0])
+    np.testing.assert_allclose(np.asarray(y), np.asarray(spmv_csr(csr, x)),
                                rtol=1e-4, atol=1e-4)
 
 

@@ -345,15 +345,6 @@ def test_kernels_interpret_match_reference(k, k_tile):
     X = jnp.asarray(np.random.default_rng(7).standard_normal(
         (n, k)).astype(np.float32))
     dense = np.asarray(coo.todense()) @ np.asarray(X)
-
-    ts = coo_to_tiled(coo, "csb", beta=128)
-    np.testing.assert_allclose(
-        np.asarray(M.tiled_spmm(ts, X, k_tile=k_tile, interpret=True)),
-        dense, rtol=RTOL, atol=ATOL)
-    csr = coo_to_csr(coo)
-    np.testing.assert_allclose(
-        np.asarray(M.csr_spmm(csr, X, k_tile=k_tile, interpret=True)),
-        dense, rtol=RTOL, atol=ATOL)
     sc = M.coo_to_sellcs(coo, c=64, sigma=128)
     np.testing.assert_allclose(
         np.asarray(M.sellcs_spmm(sc, X, k_tile=k_tile, interpret=True)),
@@ -571,20 +562,24 @@ def test_auto_impl_resolves_per_format(monkeypatch):
 
 
 def test_off_path_kernels_refuse_mosaic():
-    """Kernels that do not lower on Mosaic raise for interpret=False
-    instead of running something else; interpret mode still runs them."""
-    from repro.kernels import ops as kops
+    """The Pallas kernels are the SELL-C-σ family, behind one dispatcher:
+    a Pallas impl on any other format raises, through ``spmm`` and through
+    ``core.spmv.spmv`` alike, and ``spmv`` on SELL-C-σ is ``spmm``'s
+    k = 1 column to the bit."""
+    from repro.core import spmv
     coo = _matrices()["uniform"]
-    X = jnp.ones((coo.shape[1], 4), jnp.float32)
-    ts = coo_to_tiled(coo, "csb", beta=128)
+    X = jnp.asarray(np.random.default_rng(5).standard_normal(
+        (coo.shape[1], 4)).astype(np.float32))
     csr = coo_to_csr(coo)
-    for call in (lambda: M.tiled_spmm(ts, X),
-                 lambda: M.csr_spmm(csr, X),
-                 lambda: kops.bsr_spmv(ts, X[:, 0]),
-                 lambda: kops.merge_spmv(csr, X[:, 0]),
-                 lambda: M.spmm(csr, X, impl="pallas")):
-        with pytest.raises(NotImplementedError, match="Mosaic"):
+    for call in (lambda: M.spmm(csr, X, impl="pallas"),
+                 lambda: spmv(csr, X[:, 0], impl="pallas")):
+        with pytest.raises(TypeError, match="coo_to_sellcs"):
             call()
+    sc = M.coo_to_sellcs(coo, c=64, sigma=128)
+    x = X[:, 0]
+    np.testing.assert_array_equal(
+        np.asarray(spmv(sc, x, impl="pallas_interpret")),
+        np.asarray(M.spmm(sc, x[:, None], impl="pallas_interpret")[:, 0]))
 
 
 def test_choose_k_tile_roofline():

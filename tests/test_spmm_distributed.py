@@ -1,5 +1,5 @@
 """Distributed multi-RHS SpMM (repro.spmm.distributed) on 8 host-platform
-devices, plus the degenerate-input guards of both partitioner families.
+devices, plus the degenerate-input guards of both SELL-C-σ partitioners.
 
 Device-backed tests run in SUBPROCESSES (the device-count flag must be set
 before jax initializes; the rest of the suite keeps seeing 1 device).
@@ -226,14 +226,11 @@ print("distributed dtype contract OK")
 
 
 def test_sharded_coo_multi_rhs_and_batcher_distributed():
-    """core.distributed accepts [n, k] X; RequestBatcher drives a
-    distributed spmm_fn closure (partial last flush included)."""
+    """RequestBatcher drives a distributed spmm_fn closure (partial last
+    flush included)."""
     print(run_sub("""
 import numpy as np, jax, jax.numpy as jnp
 from repro.core import to_coo
-from repro.core.distributed import (partition_nnz, partition_rows,
-                                    spmv_merge_distributed,
-                                    spmv_row_distributed)
 from repro.data import matrices
 from repro.launch.mesh import make_mesh
 from repro.spmm import (RequestBatcher, coo_to_sellcs,
@@ -241,14 +238,6 @@ from repro.spmm import (RequestBatcher, coo_to_sellcs,
                         spmm_row_distributed)
 mesh = make_mesh((8,), ("data",))
 coo = to_coo(*matrices.mawi_like(260, 240, 2400, 0.3, 1))
-for k in (1, 8, 64):
-    X = jnp.asarray(np.random.default_rng(k).standard_normal(
-        (coo.shape[1], k)).astype(np.float32))
-    yo = np.asarray(spmm_coo(coo, X))
-    y1 = np.asarray(spmv_row_distributed(partition_rows(coo, 8), X, mesh))
-    y2 = np.asarray(spmv_merge_distributed(partition_nnz(coo, 8), X, mesh))
-    np.testing.assert_allclose(y1, yo, rtol=1e-5, atol=1e-4)
-    np.testing.assert_allclose(y2, yo, rtol=1e-5, atol=1e-4)
 
 # batcher over the mesh: 11 requests, max_batch 8 -> one full + one
 # partial flush, every ticket answered from the right column
@@ -270,7 +259,7 @@ for rid, x in zip(rids, xs):
     np.testing.assert_allclose(np.asarray(out[rid]),
                                np.asarray(spmm_coo(coo, x)),
                                rtol=1e-5, atol=1e-4)
-print("sharded COO k + distributed batcher OK")
+print("distributed batcher OK")
 """))
 
 
@@ -279,9 +268,6 @@ def test_spmm_distributed_degenerate_on_mesh():
     print(run_sub("""
 import numpy as np, jax.numpy as jnp
 from repro.core import to_coo
-from repro.core.distributed import (partition_nnz, partition_rows,
-                                    spmv_merge_distributed,
-                                    spmv_row_distributed)
 from repro.launch.mesh import make_mesh
 from repro.spmm import (coo_to_sellcs, partition_sellcs_nnz,
                         partition_sellcs_rows, spmm_merge_distributed,
@@ -304,13 +290,6 @@ np.testing.assert_allclose(np.asarray(spmm_row_distributed(
     partition_sellcs_rows(st, 8), I3, mesh)), np.eye(3), atol=1e-6)
 np.testing.assert_allclose(np.asarray(spmm_merge_distributed(
     partition_sellcs_nnz(st, 8), I3, mesh)), np.eye(3), atol=1e-6)
-# COO schedules: num_devices > m and nnz == 0
-assert np.abs(np.asarray(spmv_row_distributed(
-    partition_rows(empty, 8), X4, mesh))).max() == 0
-assert np.abs(np.asarray(spmv_merge_distributed(
-    partition_nnz(empty, 8), X4, mesh))).max() == 0
-np.testing.assert_allclose(np.asarray(spmv_row_distributed(
-    partition_rows(tiny, 8), I3, mesh)), np.eye(3), atol=1e-6)
 print("degenerate mesh cases OK")
 """))
 
@@ -326,47 +305,48 @@ def _empty_coo(m=5, n=4):
 
 def test_partition_guards_reject_bad_device_count():
     import pytest
-    from repro.core.distributed import partition_nnz, partition_rows
     from repro.spmm import (coo_to_sellcs, partition_sellcs_nnz,
                             partition_sellcs_rows)
-    coo = _empty_coo()
-    sc = coo_to_sellcs(coo, c=2)
-    for fn, arg in [(partition_rows, coo), (partition_nnz, coo),
-                    (partition_sellcs_rows, sc), (partition_sellcs_nnz, sc)]:
+    sc = coo_to_sellcs(_empty_coo(), c=2)
+    for fn in (partition_sellcs_rows, partition_sellcs_nnz):
         with pytest.raises(ValueError):
-            fn(arg, 0)
+            fn(sc, 0)
         with pytest.raises(ValueError):
-            fn(arg, -3)
+            fn(sc, -3)
 
 
 def test_partition_rows_empty_matrix_keeps_sane_shard_shapes():
-    """Regression: a zero-nnz matrix used to put every row in the last
-    band, inflating rows_per_shard to m; now bands split evenly."""
-    from repro.core.distributed import partition_nnz, partition_rows
-    coo = _empty_coo(m=64, n=16)
-    s = partition_rows(coo, 8)
-    assert s.rows.shape == (8, 1)
-    assert s.rows_per_shard == 8            # == m / P, not m
-    assert np.asarray(s.row_offset).tolist() == list(range(0, 64, 8))
-    s2 = partition_nnz(coo, 8)
-    assert s2.rows.shape == (8, 1) and s2.rows_per_shard == 1
+    """A zero-nnz matrix deals each shard one all-zero padding width-row,
+    whatever m: the shard stream never grows with the empty rows."""
+    from repro.spmm import (coo_to_sellcs, partition_sellcs_nnz,
+                            partition_sellcs_rows)
+    sc = coo_to_sellcs(_empty_coo(m=64, n=16), c=8)
+    for part in (partition_sellcs_rows, partition_sellcs_nnz):
+        s = part(sc, 8)
+        assert s.data.shape == (8, 1, 8), part.__name__
+        assert np.asarray(s.row_counts).tolist() == [0] * 8
+        assert not np.asarray(s.data).any()
 
 
 def test_partition_more_devices_than_rows():
     from repro.core import to_coo
-    from repro.core.distributed import partition_nnz, partition_rows
+    from repro.spmm import (coo_to_sellcs, partition_sellcs_nnz,
+                            partition_sellcs_rows)
     coo = to_coo(np.array([0, 1, 2], np.int32),
                  np.array([0, 1, 2], np.int32),
                  np.ones(3, np.float32), (3, 3))
-    for part in (partition_rows, partition_nnz):
-        s = part(coo, 8)
-        assert s.rows.shape[0] == 8
-        assert s.rows_per_shard >= 1
-        # local row ids stay inside the shard buffer
-        assert int(np.asarray(s.rows).max()) < s.rows_per_shard
-        # every shard offset is a valid global row (or 0 for empty shards)
-        offs = np.asarray(s.row_offset)
-        assert offs.min() >= 0 and offs.max() < 3
+    sc = coo_to_sellcs(coo, c=2, sigma=2)       # 2 slices, 8 devices
+    for part in (partition_sellcs_rows, partition_sellcs_nnz):
+        s = part(sc, 8)
+        assert s.data.shape[0] == 8
+        assert s.slices_per_shard >= 1
+        # slice ids stay inside each shard's slot space
+        assert int(np.asarray(s.slice_of).max()) < s.slices_per_shard
+        # every shard offset is a valid global slice (0 for empty shards)
+        offs = np.asarray(s.slice_offset)
+        assert offs.min() >= 0 and offs.max() < sc.num_slices
+        # every real width-row is dealt to exactly one shard
+        assert int(np.asarray(s.row_counts).sum()) == sc.data.shape[0]
 
 
 def test_partition_sellcs_roundtrip_covers_all_nnz():
